@@ -213,6 +213,9 @@ def main() -> None:
     args, _ = ap.parse_known_args()
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.compile_cache import configure
+    configure()
+
     from benchmarks import (faults, hier, homme_bgq, homme_titan,
                             mapping_tpu, minighost, roofline, serve,
                             table1_orderings)

@@ -223,7 +223,7 @@ def _accumulate_circular(out, row, nrows, s, start, length, w, dims, k):
 
 SCORE_BACKENDS = ("numpy", "jax", "pallas")
 
-# Silent fallback chain per requested backend: the first importable
+# Fallback chain per requested backend (warned once): the first importable
 # entry wins, so "pallas" degrades to the jit-compiled jax scorer and
 # finally to numpy when the accelerator stack is unavailable.
 _BACKEND_CHAIN = {
@@ -235,13 +235,12 @@ _BACKEND_CHAIN = {
 _JAX_EVAL = False     # memoised import: False = untried, None = unavailable
 _PALLAS_EVAL = False  # likewise for the Pallas mapscore kernel
 
-# Import/initialisation failures that legitimately disable an
-# accelerator backend.  Anything else (a genuine bug, a runtime device
-# fault) must PROPAGATE — the serve layer's degradation ladder handles
-# those per-request instead of silently pinning the process to a slower
-# rung (ISSUE 7 satellite: the old guards were bare ``except
-# Exception``, which swallowed everything).
-_IMPORT_FAILURES = (ImportError, AttributeError, OSError, RuntimeError)
+# Only a missing package disables an accelerator backend.  Anything
+# else (a genuine bug, a broken device that raises RuntimeError or
+# OSError while initialising) must PROPAGATE — the serve layer's
+# degradation ladder handles those per-request instead of silently
+# pinning the process to a slower rung.
+_IMPORT_FAILURES = (ImportError,)
 
 # cause of each unavailable backend (repr), for the one-shot warning
 _FALLBACK_CAUSE: dict[str, str] = {}

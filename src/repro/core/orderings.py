@@ -48,7 +48,7 @@ BACKENDS = ("vectorized", "recursive", "jax")
 
 # Partitioner device backends (the pipeline-level knob): "jax" runs the
 # level-synchronous sweep on accelerator (:mod:`repro.core.partition_jax`)
-# and falls back silently to "numpy" (the vectorized engine) when the
+# and falls back to "numpy" (the vectorized engine, warned once) when the
 # jax stack is unavailable — same resolved-once discipline as the
 # score-backend chain in :mod:`repro.core.metrics`.
 PARTITION_BACKENDS = ("numpy", "jax")
@@ -58,11 +58,11 @@ _PARTITION_CHAIN = {"numpy": ("numpy",), "jax": ("jax", "numpy")}
 # module object = ready
 _JAX_PART = False
 
-# Import/initialisation failures that legitimately disable the device
-# partition engine; runtime faults must propagate to the caller (the
+# Only a missing package disables the device partition engine; a
+# broken device and runtime faults must propagate to the caller (the
 # serve layer's degradation ladder owns per-request recovery).  Mirrors
 # ``metrics._IMPORT_FAILURES``.
-_IMPORT_FAILURES = (ImportError, AttributeError, OSError, RuntimeError)
+_IMPORT_FAILURES = (ImportError,)
 
 _JAX_PART_CAUSE: str | None = None
 _WARNED_FALLBACK = False
@@ -161,7 +161,7 @@ def order_points(
     backend : ``"vectorized"`` (level-synchronous engine, default),
         ``"recursive"`` (the original reference recursion) or ``"jax"``
         (the device engine of :mod:`repro.core.partition_jax`, falling
-        back silently to the vectorized engine when jax is missing).
+        back to the vectorized engine, warned once, when jax is missing).
         All return bit-identical part numbers.
 
     Returns

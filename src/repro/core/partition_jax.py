@@ -1,51 +1,56 @@
 """JAX backend for the level-synchronous Multi-Jagged partitioner.
 
 Implements the ``vectorized_order`` / ``vectorized_order_batched``
-contract on device: the recursion-level loop of paper Algorithm 2 runs
-as a ``lax.while_loop`` over fixed-shape state, with
+contract on device: the recursion-level loop of paper Algorithm 2
+runs as a ``lax.while_loop`` over fixed-shape state, with
 
-- the per-level *segmented stable partition* expressed as one
-  ``lax.sort`` over composite ``(segment, coordinate)`` keys,
+- the per-level *segmented stable partition* expressed as one narrow
+  ``lax.sort`` per candidate block over int32 ``(segment, coordinate
+  rank, position)`` keys, whose permutation then gathers the point ids,
 - segment extents (longest-dimension selection) via
-  ``jax.ops.segment_max/min`` keyed by segment start,
-- cut placement via per-position rank comparisons (unit weights) or a
-  sequential per-segment weight prefix scan (``lax.scan``), and
-- the SFC coordinate flips as masked negations.
+  ``jax.ops.segment_max/min`` of the ranks, keyed by segment start,
+- cut placement from a host-built table of the unit-weight recursion
+  (or a sequential per-segment weight prefix scan, ``lax.scan``), and
+- the SFC coordinate flips as per-segment sign bits.
 
 The representation is *positional*: instead of a growing segment table,
 every one of the ``nb_b * npts_b`` padded point slots carries its
-segment's ``(start, size, nparts)`` — constant within a segment, so the
-within-segment stable sorts never have to move the table at all and the
-whole state is fixed-shape int32/float64 arrays (what ``while_loop``
-requires).  Candidate ``b`` of a batched sweep owns slot block
+segment's ``(start, size, nparts)`` and flip bits — constant within a
+segment, so the within-segment sorts never have to move them and the
+whole state is fixed-shape arrays (what ``while_loop`` requires).
+Candidate ``b`` of a batched sweep owns slot block
 ``[b*npts_b, (b+1)*npts_b)``; padding tails are closed single-part
 segments that the sweep never activates, so bucketing changes no result
 bit.
 
 Bit-identity with the numpy engines (the ``np.lexsort`` tie order of
-``partition._exact_order`` is the oracle) requires three deliberate
-choices, each load-bearing:
+``partition._exact_order`` is the oracle) holds on a TPU too, where
+float64 is emulated with pairs of float32 and does not round like IEEE:
 
-- ``jax_enable_x64``: every cut target (``size * npl/np``) and weight
-  prefix sum in the oracle is float64; f32 rounds cuts differently.
-- ``+ 0.0`` key canonicalisation before ``lax.sort``: XLA's total-order
-  float comparator sorts ``-0.0 < 0.0`` where numpy treats them as
-  equal ties — and the FZ/FZlow/Gray flips mint ``-0.0`` routinely.
-- a *sequential* ``lax.scan`` for weighted prefix sums: a parallel
-  prefix scan re-associates the additions and rounds differently from
-  numpy's left-to-right ``np.cumsum``.
+- coordinates enter as dense per-dimension ranks (``np.unique`` on the
+  host), so the sort keys are exact int32: equal values tie, a flip
+  negates the rank, and ``-0.0`` ties with ``0.0`` as in numpy;
+- unit-weight cuts depend only on ``(segment size, part count)``, so the
+  host runs the oracle's own ``_uniform_cuts`` arithmetic over the
+  recursion once and ships the resulting cut table;
+- the Hilbert grid is quantised on the host by the oracle's quantiser.
+
+What stays in float64 on the device is the longest-dimension extent
+(compared with the oracle's ``1e-12`` tolerance, far above the error
+of the emulation) and the weighted cut arithmetic (a sequential
+``lax.scan``, exact on integer weights).
 
 Shape bucketing + the keyed compile cache mirror
 :mod:`repro.core.metrics_jax`: point counts pad to power-of-two buckets
 (``PART_BUCKET_MIN`` floor) with the real count passed as a *traced*
-scalar, the ``uneven_prime`` split table ships as a padded traced
-array, and :func:`partition_cache_stats` exposes the truthful
-compile-count counters benchmarks assert on.
+scalar, the ``uneven_prime`` split table and the cut table ship as
+padded traced arrays, and :func:`partition_cache_stats` exposes the
+truthful compile-count counters benchmarks assert on.
 
 This module imports jax at module level — callers go through
 ``repro.core.orderings`` (``backend="jax"`` / the
-``resolve_partition_backend`` chain), which falls back silently to the
-numpy engine when the import fails.
+``resolve_partition_backend`` chain), which resolves to the numpy
+engine only when jax cannot be imported.
 """
 
 from __future__ import annotations
@@ -56,9 +61,9 @@ import numpy as np
 
 import jax
 
-# Bit-identity with the float64 numpy oracle is impossible at f32 (cut
-# targets and weight prefix sums round differently), so this backend
-# requires x64.  Process-wide but safe: the score backends pin f32
+# The extents and weighted prefix sums are float64 in the oracle, and
+# f32 rounds them differently, so this backend requires x64.
+# Process-wide but safe: the score backends pin f32 and int32
 # explicitly on every array they build.
 jax.config.update("jax_enable_x64", True)
 
@@ -68,13 +73,15 @@ from jax import lax  # noqa: E402
 from repro import obs  # noqa: E402
 
 from .metrics_jax import bucket_size, pad_axis  # noqa: E402
-from .orderings import _split_counts, hilbert_bits  # noqa: E402
+from .orderings import (_hilbert_quantise, _split_counts,  # noqa: E402
+                        hilbert_bits)
+from .partition import _uniform_cuts  # noqa: E402
 
 __all__ = ["order_points_jax", "order_points_batched_jax",
            "partition_cache_stats", "reset_partition_cache"]
 
 PART_BUCKET_MIN = 256  # smallest padded point-count bucket
-TAB_MIN = 16           # smallest padded split-table bucket
+TAB_MIN = 16           # smallest padded split-/cut-table bucket
 
 _I32 = jnp.int32
 _F64 = jnp.float64
@@ -116,18 +123,53 @@ def _largest(m: int) -> int:
     return max(2, m - 1)
 
 
+@functools.lru_cache(maxsize=64)
+def _cut_table(n: int, nparts: int, uneven_prime: bool) -> np.ndarray:
+    """``(rows, 3)`` int32 ``[size, nparts, k]``: the cut index ``k`` of
+    every active segment of the unit-weight recursion of ``n`` points
+    into ``nparts`` parts.
+
+    Unit-weight cuts depend only on a segment's size and part count, so
+    the recursion's segment tree is the same for every point cloud.  The
+    host walks it once with the oracle's own ``_uniform_cuts`` (float64
+    IEEE arithmetic), and the device looks the cuts up instead of
+    recomputing ``size * npl / np``, which a TPU would round in its
+    emulated float64.
+    """
+    rows = {}
+    todo = {(int(n), int(nparts))}
+    while todo:
+        nxt = set()
+        for size, p in sorted(todo):
+            if size <= 1 or p <= 1 or (size, p) in rows:
+                continue
+            npl = _split_counts(p, uneven_prime)[0]
+            k = int(_uniform_cuts(np.array([size]), np.array([npl / p]))[0])
+            rows[(size, p)] = k
+            nxt |= {(k, npl), (size - k, p - npl)}
+        todo = nxt
+    tab = np.array([(s_, p_, k) for (s_, p_), k in sorted(rows.items())],
+                   dtype=np.int32).reshape(-1, 3)
+    return tab
+
+
 # ---------------------------------------------------------------------------
 # the device sweep
 # ---------------------------------------------------------------------------
 
-def _sweep(cols, sdo, w, npl_tab, n, B, nparts, *, d, sfc, longest_dim,
-           weighted, npts_b, nb_b):
+def _sweep(ranks, uvals, sdo, w, npl_tab, cut_tab, n, B, nparts, *, d,
+           sfc, longest_dim, weighted, npts_b, nb_b):
     """One whole batched partition on device.
 
-    cols    : (d, npts_b) f64 — the shared, padded point cloud.
+    ranks   : (d, npts_b) i32 — dense per-dimension rank of each padded
+              point's coordinate (equal values share a rank).
+    uvals   : (d, npts_b) f64 — the distinct coordinate values by rank
+              (read only for longest-dimension extents).
     sdo     : (nb_b, d) i32 — per-candidate cut-dimension priority rows.
     w       : (npts_b,) f64 — point weights (ignored unless weighted).
     npl_tab : (tab_b,) i32 — npl lookup by current part count.
+    cut_tab : (cut_b, 3) i32 — unit-weight cut table (:func:`_cut_table`;
+              ignored when weighted).
     n, B, nparts : traced scalars (real points / candidates / parts), so
         they stay OUT of the compile key; only the buckets are static.
 
@@ -147,33 +189,36 @@ def _sweep(cols, sdo, w, npl_tab, n, B, nparts, *, d, sfc, longest_dim,
                          ).astype(_I32)
     seg_np = jnp.where(real, nparts, 1).astype(_I32)
     mu = jnp.zeros(N, dtype=_I32)
-    pts = pos  # global slot id of the point at each position
-    colsN = jnp.tile(cols, (1, nb_b))          # (d, N), diverges via flips
-    wN = jnp.tile(w, nb_b) if weighted else None
+    pts = local  # block-local id of the point at each position
+    flip = jnp.zeros((d, N), dtype=bool)       # per-segment coordinate signs
     sdoN = jnp.repeat(sdo, npts_b, axis=0)     # (N, d), loop-invariant
     dims_col = jnp.arange(d, dtype=_I32)[:, None]
+    lpos = local.reshape(nb_b, npts_b)
+    cut_size, cut_np, cut_k = cut_tab[:, 0], cut_tab[:, 1], cut_tab[:, 2]
 
     def cond(state):
         _, _, _, _, seg_size, seg_np, _ = state
         return jnp.any((seg_np > 1) & (seg_size > 1))
 
     def body(state):
-        level, pts, mu, seg_start, seg_size, seg_np, colsN = state
+        level, pts, mu, seg_start, seg_size, seg_np, flip = state
         act = (seg_np > 1) & (seg_size > 1)
+        R = jnp.take(ranks, pts, axis=1)                        # (d, N)
 
         # --- cut dimension (reference: _pick_cut_dims / alternation) ----
         if d == 1:
             cut = jnp.zeros(N, dtype=_I32)
         elif longest_dim:
+            # a segment's flips are uniform, so its extent is that of
+            # the unflipped values: max - min by rank, exactly as IEEE
+            # subtracts the flipped max and min in the oracle
             exts = []
             for j in range(d):
-                hi = jax.ops.segment_max(colsN[j], seg_start,
-                                         num_segments=N,
+                hi = jax.ops.segment_max(R[j], seg_start, num_segments=N,
                                          indices_are_sorted=True)
-                lo = jax.ops.segment_min(colsN[j], seg_start,
-                                         num_segments=N,
+                lo = jax.ops.segment_min(R[j], seg_start, num_segments=N,
                                          indices_are_sorted=True)
-                exts.append((hi - lo)[seg_start])
+                exts.append(uvals[j][hi[seg_start]] - uvals[j][lo[seg_start]])
             ext = jnp.stack(exts, axis=1)                      # (N, d)
             pri = jnp.take_along_axis(ext, sdoN, axis=1)
             best_p = jnp.zeros(N, dtype=_I32)
@@ -187,26 +232,31 @@ def _sweep(cols, sdo, w, npl_tab, n, B, nparts, *, d, sfc, longest_dim,
             cut = jnp.take(sdoN, level % d, axis=1).astype(_I32)
 
         # --- segmented stable sort by the cut coordinate ----------------
-        # ``+ 0.0`` canonicalises -0.0 so XLA's total-order comparator
-        # agrees with numpy's equal-zeros tie handling
-        flat = cut.astype(jnp.int64) * N + jnp.arange(N, dtype=jnp.int64)
-        ckey = colsN.reshape(-1)[flat] + 0.0
-        ops = (seg_start, ckey, pts) + tuple(colsN[j] for j in range(d))
-        ops = lax.sort(ops, num_keys=2, is_stable=True)
-        pts = ops[2]
-        colsN = jnp.stack(ops[3:], axis=0)
-        # mu / seg_* are constant within segments, so the within-segment
-        # permutation leaves them correct without riding the sort
+        # the position is the last key, so the order is the stable one;
+        # only three int32 operands ride the sort, the point ids follow
+        # by one gather of the permutation
+        rc = jnp.take_along_axis(R, cut[None, :], axis=0)[0]
+        fc = jnp.take_along_axis(flip, cut[None, :], axis=0)[0]
+        ckey = jnp.where(fc, -rc, rc)
+        _, _, perm = lax.sort(
+            ((seg_start - blk0).reshape(nb_b, npts_b),
+             ckey.reshape(nb_b, npts_b), lpos),
+            dimension=1, num_keys=3, is_stable=False)
+        pts = jnp.take_along_axis(pts.reshape(nb_b, npts_b), perm,
+                                  axis=1).reshape(N)
+        # mu / seg_* / flip are constant within segments, so the
+        # within-segment permutation leaves them correct
         rank = pos - seg_start
 
         # --- cut placement (reference: _uniform_cuts / _padded_cuts) ----
         npl = npl_tab[seg_np]
-        ratio = npl.astype(_F64) / seg_np.astype(_F64)
-        target = seg_size.astype(_F64) * ratio
         if not weighted:
-            below = (rank + 1).astype(_F64) < target
+            hit = ((seg_size[:, None] == cut_size[None, :])
+                   & (seg_np[:, None] == cut_np[None, :]))
+            k = jnp.sum(jnp.where(hit, cut_k[None, :], 0), axis=1)
         else:
-            w_cur = wN[pts]
+            ratio = npl.astype(_F64) / seg_np.astype(_F64)
+            w_cur = w[pts]
             is_start = rank == 0
 
             def scan_f(c, xw):
@@ -216,64 +266,62 @@ def _sweep(cols, sdo, w, npl_tab, n, B, nparts, *, d, sfc, longest_dim,
 
             _, cw = lax.scan(scan_f, jnp.float64(0.0), (w_cur, is_start),
                              unroll=8)
-            last = (seg_start + seg_size - 1).astype(jnp.int64)
+            last = seg_start + seg_size - 1
             below = cw < cw[last] * ratio
-        k = jax.ops.segment_sum(below.astype(_I32), seg_start,
-                                num_segments=N,
-                                indices_are_sorted=True)[seg_start] + 1
-        k = jnp.clip(k, 1, jnp.maximum(seg_size - 1, 1)).astype(_I32)
+            k = jax.ops.segment_sum(below.astype(_I32), seg_start,
+                                    num_segments=N,
+                                    indices_are_sorted=True)[seg_start] + 1
+            k = jnp.clip(k, 1, jnp.maximum(seg_size - 1, 1))
+        k = k.astype(_I32)
 
         # --- flips + part numbers + child segments ----------------------
         right = rank >= k
         ar = act & right
         mu = mu + jnp.where(ar, npl, 0).astype(_I32)
         if sfc == "Gray":
-            colsN = jnp.where(ar[None, :], -colsN, colsN)
+            flip = flip ^ ar[None, :]
         elif sfc == "FZ":
-            oh = (dims_col == cut[None, :]) & ar[None, :]
-            colsN = jnp.where(oh, -colsN, colsN)
+            flip = flip ^ ((dims_col == cut[None, :]) & ar[None, :])
         elif sfc == "FZlow":
-            oh = (dims_col == cut[None, :]) & (act & ~right)[None, :]
-            colsN = jnp.where(oh, -colsN, colsN)
+            flip = flip ^ ((dims_col == cut[None, :])
+                           & (act & ~right)[None, :])
         seg_np = jnp.where(act, jnp.where(right, seg_np - npl, npl),
                            seg_np).astype(_I32)
         seg_start = jnp.where(ar, seg_start + k, seg_start).astype(_I32)
         seg_size = jnp.where(act, jnp.where(right, seg_size - k, k),
                              seg_size).astype(_I32)
-        return (level + 1, pts, mu, seg_start, seg_size, seg_np, colsN)
+        return (level + 1, pts, mu, seg_start, seg_size, seg_np, flip)
 
-    state = (jnp.int32(0), pts, mu, seg_start, seg_size, seg_np, colsN)
+    state = (jnp.int32(0), pts, mu, seg_start, seg_size, seg_np, flip)
     state = lax.while_loop(cond, body, state)
     _, pts, mu = state[0], state[1], state[2]
-    out = jnp.zeros(N, dtype=_I32).at[pts].set(mu, unique_indices=True)
+    out = jnp.zeros(N, dtype=_I32).at[blk0 + pts].set(mu,
+                                                      unique_indices=True)
     return out.reshape(nb_b, npts_b)
 
 
-def _hilbert_sweep(cols, sdo, w, npl_tab, n, B, nparts, *, d, bits,
-                   weighted, npts_b, nb_b):
+_KEY_MASK = (1 << 31) - 1
+
+
+def _hilbert_sweep(grid, uvals, sdo, w, npl_tab, cut_tab, n, B, nparts,
+                   *, d, bits, weighted, npts_b, nb_b):
     """Batched Hilbert numbering on device (Skilling's transpose
     algorithm, mirroring ``orderings.hilbert_index`` /
     ``orderings._hilbert_split`` op for op).
 
     Same call signature as :func:`_sweep` so both share ``_engine``'s
-    compile cache.  ``bits`` is static — the Skilling state machine and
-    the bit interleave unroll over it.  The per-candidate dim-order is
-    folded in as a *column gather on the quantised grid*: quantisation
-    is per-dimension, so it commutes with column permutation and runs
-    once for the whole sweep; candidate ``b`` is then bit-identical to
-    the host ``order_points(coords[:, dim_orders[b]], nparts, "H")``.
+    compile cache.  ``grid`` is the (d, npts_b) int32 Hilbert grid,
+    quantised on the host by the oracle's ``_hilbert_quantise``.
+    ``bits`` is static — the Skilling state machine and the bit
+    interleave unroll over it.  The per-candidate dim-order is a
+    *column gather on the quantised grid*: quantisation is
+    per-dimension, so it commutes with column permutation; candidate
+    ``b`` is then bit-identical to the host
+    ``order_points(coords[:, dim_orders[b]], nparts, "H")``.
     """
-    del npl_tab  # Hilbert splits need no npl table
+    del uvals, npl_tab, cut_tab  # Hilbert splits need no MJ tables
     N = nb_b * npts_b
-    side = 1 << bits
-    # --- quantise once (reference: orderings._hilbert_quantise) --------
-    maskp = jnp.arange(npts_b) < n
-    lo = jnp.min(jnp.where(maskp[None, :], cols, jnp.inf), axis=1)
-    hi = jnp.max(jnp.where(maskp[None, :], cols, -jnp.inf), axis=1)
-    span = hi - lo
-    span = jnp.where(span > 0, span, 1.0)
-    q = jnp.clip(jnp.round((cols - lo[:, None]) / span[:, None]
-                           * (side - 1)).astype(jnp.int64), 0, side - 1)
+    q = grid.astype(jnp.int64)
     # --- fold the dim-order: Xc[i] = q[sdo[b, i]] per candidate --------
     sdo64 = sdo.astype(jnp.int64)
     Xc = [jnp.take(q, sdo64[:, i], axis=0).reshape(-1) for i in range(d)]
@@ -306,22 +354,27 @@ def _hilbert_sweep(cols, sdo, w, npl_tab, n, B, nparts, *, d, bits,
         for i in range(d):  # interleave: bit b of dim i -> b*d + (d-1-i)
             for b in range(bits):
                 h = h | (((Xc[i] >> b) & 1) << (b * d + (d - 1 - i)))
-    # --- segmented stable sort + split (ref: _hilbert_split) -----------
+    # --- per-block stable sort + split (ref: _hilbert_split) -----------
+    # h < 2^(bits*d) <= 2^62 splits into two int32 keys; the pad key
+    # (all ones) sorts no earlier than any real key, and the position
+    # key puts the pad tail after the real points
     pos = jnp.arange(N, dtype=_I32)
-    block = pos // npts_b
-    realN = ((pos % npts_b) < n) & (block < B)
-    # h < 2^(bits*d) <= 2^62, so this pad key sorts strictly last
-    hkey = jnp.where(realN, h, jnp.int64(1) << 62)
-    ops = (block, hkey, pos, w[pos % npts_b])
-    ops = lax.sort(ops, num_keys=2, is_stable=True)
-    pts, w_srt = ops[2], ops[3]
-    rank = (jnp.arange(N) % npts_b).astype(jnp.int64)
+    realN = ((pos % npts_b) < n) & ((pos // npts_b) < B)
+    hhi = jnp.where(realN, (h >> 31).astype(_I32), _KEY_MASK)
+    hlo = jnp.where(realN, (h & _KEY_MASK).astype(_I32), _KEY_MASK)
+    lpos = (pos % npts_b).reshape(nb_b, npts_b)
+    _, _, perm = lax.sort(
+        (hhi.reshape(nb_b, npts_b), hlo.reshape(nb_b, npts_b), lpos),
+        dimension=1, num_keys=3, is_stable=False)
+    pts = perm.reshape(N)
+    rank = (pos % npts_b).astype(jnp.int64)
     n64 = n.astype(jnp.int64)
     np64 = nparts.astype(jnp.int64)
     if not weighted:
         # closed form of searchsorted((arange(1,np)*n)//np, j, "right")
         part = jnp.minimum(((rank + 1) * np64 - 1) // n64, np64 - 1)
     else:
+        w_srt = w[pts]
         is_start = rank == 0
 
         def scan_f(c, xw):
@@ -340,26 +393,29 @@ def _hilbert_sweep(cols, sdo, w, npl_tab, n, B, nparts, *, d, bits,
         total = (incl[last] - w_last) + w_last
         part = jnp.minimum((cwx / total * np64.astype(_F64))
                            .astype(jnp.int64), np64 - 1)
-    out = jnp.zeros(N, dtype=_I32).at[pts].set(
+    blk0 = (pos // npts_b) * npts_b
+    out = jnp.zeros(N, dtype=_I32).at[blk0 + pts].set(
         part.astype(_I32), unique_indices=True)
     return out.reshape(nb_b, npts_b)
 
 
 @functools.lru_cache(maxsize=None)
-def _engine(d, sfc, longest_dim, weighted, npts_b, nb_b, tab_b, bits):
+def _engine(d, sfc, longest_dim, weighted, npts_b, nb_b, tab_b, cut_b,
+            bits):
     """One jit-compiled sweep per (engine knobs, shape bucket).
 
-    ``tab_b`` is part of the key even though the function never reads
-    it: every cache entry then sees exactly ONE input shape set, so the
-    ``lru_cache`` hit/miss counters are a truthful compile-count proxy
-    (mirrors ``metrics_jax._scorer``).  ``bits`` is the static Hilbert
-    resolution (0 for the MJ sweeps); call sites MUST pass it
-    positionally — ``lru_cache`` keys keyword spellings separately and
-    a split key would double-compile.  For ``sfc == "H"`` callers
-    canonicalise ``longest_dim=True`` (Hilbert has no cut dimensions)
-    so the knob cannot fragment the cache either.
+    ``tab_b`` and ``cut_b`` are part of the key even though the function
+    never reads them: every cache entry then sees exactly ONE input
+    shape set, so the ``lru_cache`` hit/miss counters are a truthful
+    compile-count proxy (mirrors ``metrics_jax._scorer``).  ``bits`` is
+    the static Hilbert resolution (0 for the MJ sweeps); call sites
+    MUST pass every key positionally — ``lru_cache`` keys keyword
+    spellings separately and a split key would double-compile.  For
+    ``sfc == "H"`` callers canonicalise ``longest_dim=True`` (Hilbert
+    has no cut dimensions) so the knob cannot fragment the cache
+    either.
     """
-    del tab_b  # shape part of the key only
+    del tab_b, cut_b  # shape part of the key only
     if sfc == "H":
         return jax.jit(functools.partial(
             _hilbert_sweep, d=d, bits=bits, weighted=weighted,
@@ -380,23 +436,44 @@ partition_cache_stats, reset_partition_cache = \
 # host entry points (the orderings-module backend contract)
 # ---------------------------------------------------------------------------
 
-def _prepare(coords, nparts, dim_orders, weights, uneven_prime):
-    """Padded device inputs + static bucket keys for one batched call."""
+def _prepare(coords, nparts, sfc, dim_orders, weights, uneven_prime):
+    """Padded device inputs of one batched call and the shape keys of
+    :func:`_engine`: returns ``(args, (npts_b, nb_b, tab_b, cut_b,
+    bits))``, ``args`` being the engine's first six positional inputs
+    (per-dim ranks or the Hilbert grid, distinct values, dim orders,
+    weights, split table, cut table)."""
     n, d = coords.shape
     B = len(dim_orders)
     npts_b = bucket_size(n, PART_BUCKET_MIN)
     nb_b = bucket_size(B, lo=1)
-    cols = pad_axis(np.ascontiguousarray(coords.T), npts_b, axis=1)
+    if sfc == "H":
+        bits = hilbert_bits(n, d)
+        keys = _hilbert_quantise(coords, bits).T.astype(np.int32)
+        uvals = np.zeros((d, 1), dtype=np.float64)
+    else:
+        bits = 0
+        keys = np.empty((d, n), dtype=np.int32)
+        uvals = np.zeros((d, npts_b), dtype=np.float64)
+        for j in range(d):
+            u, inv = np.unique(coords[:, j], return_inverse=True)
+            keys[j] = inv.reshape(-1)
+            uvals[j, :len(u)] = u
+    keys = pad_axis(keys, npts_b, axis=1)
     sdo = np.tile(np.arange(d, dtype=np.int32), (nb_b, 1))
     sdo[:B] = dim_orders
     if weights is None:
         w = np.ones(npts_b, dtype=np.float64)
+        cut = _cut_table(n, int(nparts), bool(uneven_prime))
     else:
         w = pad_axis(np.asarray(weights, dtype=np.float64), npts_b)
+        cut = np.zeros((0, 3), dtype=np.int32)
+    cut_b = bucket_size(len(cut), lo=TAB_MIN)
+    cut = pad_axis(cut, cut_b)
     tab = _split_table(int(nparts), bool(uneven_prime))
     tab_b = bucket_size(len(tab), lo=TAB_MIN)
     tab = pad_axis(tab, tab_b)
-    return cols, sdo, w, tab, npts_b, nb_b, tab_b
+    return (keys, uvals, sdo, w, tab, cut), (npts_b, nb_b, tab_b, cut_b,
+                                             bits)
 
 
 def order_points_batched_jax(
@@ -415,7 +492,9 @@ def order_points_batched_jax(
     coords = np.asarray(coords, dtype=np.float64)
     dim_orders = np.atleast_2d(np.asarray(dim_orders, dtype=np.int64))
     B = len(dim_orders)
-    n, d = coords.shape if coords.ndim == 2 else (len(coords), 1)
+    if coords.ndim == 1:
+        coords = coords[:, None]
+    n, d = coords.shape
     if nparts <= 1 or n == 0:
         return np.zeros((B, n), dtype=np.int64)
     npts_b = bucket_size(n, PART_BUCKET_MIN)
@@ -425,19 +504,15 @@ def order_points_batched_jax(
         return vectorized_order_batched(  # pragma: no cover
             coords, nparts, sfc, dim_orders=dim_orders, weights=weights,
             longest_dim=longest_dim, uneven_prime=uneven_prime)
-    cols, sdo, w, tab, npts_b, nb_b, tab_b = _prepare(
-        coords, nparts, dim_orders, weights, uneven_prime)
-    if sfc == "H":
-        bits, ld = hilbert_bits(n, d), True  # canonical H cache key
-    else:
-        bits, ld = 0, bool(longest_dim)
+    args, (npts_b, nb_b, tab_b, cut_b, bits) = _prepare(
+        coords, nparts, sfc, dim_orders, weights, uneven_prime)
+    ld = True if sfc == "H" else bool(longest_dim)  # canonical H cache key
     misses0 = _engine.cache_info().misses
     fn = _engine(d, sfc, ld, weights is not None,
-                 npts_b, nb_b, tab_b, bits)
+                 npts_b, nb_b, tab_b, cut_b, bits)
     obs.annotate(compile_cache=(
         "miss" if _engine.cache_info().misses > misses0 else "hit"))
-    out = fn(cols, sdo, w, tab, np.int32(n), np.int32(B),
-             np.int32(nparts))
+    out = fn(*args, np.int32(n), np.int32(B), np.int32(nparts))
     return np.asarray(out)[:B, :n].astype(np.int64)
 
 

@@ -381,9 +381,11 @@ def map_hierarchical(
         "trace_id": root.trace_id,
     }
     stats.update(rstats)
+    if coarse_best.stats.get("fused"):
+        stats["fused"] = True
+        stats["fused_score_backend"] = \
+            coarse_best.stats["fused_score_backend"]
     if fused_refined:
         stats["fused_refine"] = True
-        stats["fused_score_backend"] = \
-            coarse_best.stats.get("fused_score_backend")
     return MappingResult(t2p, rotation=coarse_best.rotation,
                          score=float(rstats["refine_final"]), stats=stats)

@@ -11,7 +11,7 @@ kernel once per (machine structure, bucket) via a compile cache whose
 hit/miss counters land in ``benchmarks/run.py --json``.  On CPU the
 kernel runs in Pallas interpret mode (the parity-tested path); machines
 whose link accumulators would not fit the VMEM budget fall back
-silently to the jax scorer (and from there to numpy).
+to the jax scorer, with a one-time warning.
 """
 
 from __future__ import annotations
@@ -77,6 +77,22 @@ def _warn_vmem_fallback(machine: Machine) -> None:
         "backend", RuntimeWarning, stacklevel=3)
 
 
+def inv_bandwidth(machine: Machine) -> np.ndarray:
+    """(sum of accumulator sublanes, 1) f32 1/bandwidth per link column:
+    one block per network dim, ``acc_shapes`` sublanes tall and zero
+    past the dim's extent, so the kernel reads each dim's block at a
+    sublane-aligned offset."""
+    dims = tuple(int(x) for x in machine.dims)
+    blocks = []
+    for k, (sp, _) in enumerate(acc_shapes(dims, machine.core_dims)):
+        col = np.zeros(sp, dtype=np.float64)
+        col[:dims[k]] = 1.0 / np.asarray(machine.bw(k, np.arange(dims[k])),
+                                         dtype=np.float64)
+        blocks.append(col)
+    inv = np.concatenate(blocks) if blocks else np.zeros(8)
+    return inv.astype(np.float32).reshape(-1, 1)
+
+
 def vmem_accumulator_bytes(machine: Machine) -> int:
     """Bytes of VMEM link-accumulator scratch the kernel would allocate
     for ``machine`` (two f32 buffers per network dim)."""
@@ -132,7 +148,6 @@ def evaluate_candidates_pallas(machine: Machine, task_edges: np.ndarray,
     if interpret is None:
         interpret = not _on_tpu()
 
-    nd = machine.ndim - machine.core_dims
     dims = tuple(int(x) for x in machine.dims)
     wrap = tuple(bool(x) for x in machine.wrap)
     ncols = machine.ndim if traffic else min(coord_stack.shape[-1],
@@ -147,14 +162,8 @@ def evaluate_candidates_pallas(machine: Machine, task_edges: np.ndarray,
     ne_b = bucket_size(ne)
     tile = min(TILE_MAX, ne_b)
     w_p = jnp.asarray(
-        pad_axis(w.astype(np.float32).reshape(-1, 1), ne_b, axis=0))
-    inv_bw = None
-    if traffic:
-        inv = np.concatenate([
-            1.0 / np.asarray(machine.bw(k, np.arange(dims[k])),
-                             dtype=np.float64)
-            for k in range(nd)]) if nd else np.zeros(0)
-        inv_bw = jnp.asarray(inv.reshape(-1, 1), dtype=jnp.float32)
+        pad_axis(w.astype(np.float32).reshape(1, -1), ne_b, axis=1))
+    inv_bw = jnp.asarray(inv_bandwidth(machine)) if traffic else None
 
     # pow2 candidate chunks bounded by chunk_elems (rounded down so the
     # bound holds): the host-side src/dst gathers stay capped on huge
@@ -165,30 +174,32 @@ def evaluate_candidates_pallas(machine: Machine, task_edges: np.ndarray,
     while c0 < nb:
         n_here = min(chunk, nb - c0)
         nb_b = n_here if n_here == chunk else bucket_size(n_here, lo=1)
-        cs = coord_stack[c0:c0 + n_here]
-        src = pad_axis(pad_axis(
-            cs[:, edges[:, 0], :ncols].astype(np.int32), ne_b, axis=1),
-            nb_b, axis=0)
-        dst = pad_axis(pad_axis(
-            cs[:, edges[:, 1], :ncols].astype(np.int32), ne_b, axis=1),
-            nb_b, axis=0)
+        # (nb, ncols, E): messages on lanes
+        cs = np.swapaxes(coord_stack[c0:c0 + n_here, :, :ncols], 1, 2
+                         ).astype(np.int32)
+        src = pad_axis(pad_axis(cs[:, :, edges[:, 0]], ne_b, axis=2),
+                       nb_b, axis=0)
+        dst = pad_axis(pad_axis(cs[:, :, edges[:, 1]], ne_b, axis=2),
+                       nb_b, axis=0)
         args = [jnp.asarray(src), jnp.asarray(dst), w_p]
         if traffic:
             args.append(inv_bw)
         misses0 = _compiled.cache_info().misses
         fn = _compiled(dims, wrap, machine.core_dims, traffic, ne_b, tile,
                        nb_b, ncols, bool(interpret))
-        obs.annotate(compile_cache=(
-            "miss" if _compiled.cache_info().misses > misses0 else "hit"))
+        miss = _compiled.cache_info().misses > misses0
+        obs.annotate(compile_cache="miss" if miss else "hit")
+        if miss and interpret:
+            obs.counter("pallas.interpret_compiles")
         outf, outi = fn(*args)
         outf = np.asarray(outf)
         outi = np.asarray(outi)
         sl = slice(c0, c0 + n_here)
-        out["weighted_hops"][sl] = outf[:n_here, 0].astype(np.float64)
-        out["total_hops"][sl] = outi[:n_here, 0].astype(np.int64)
+        out["weighted_hops"][sl] = outf[:n_here, 0, 0].astype(np.float64)
+        out["total_hops"][sl] = outi[:n_here, 0, 0].astype(np.int64)
         if traffic:
-            out["data_max"][sl] = outf[:n_here, 1].astype(np.float64)
-            out["latency_max"][sl] = outf[:n_here, 2].astype(np.float64)
+            out["data_max"][sl] = outf[:n_here, 0, 1].astype(np.float64)
+            out["latency_max"][sl] = outf[:n_here, 0, 2].astype(np.float64)
         c0 += n_here
     out["average_hops"] = out["total_hops"] / ne
     return out

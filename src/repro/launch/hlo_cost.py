@@ -38,16 +38,10 @@ _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalised across jax versions.
+    """``compiled.cost_analysis()`` as a plain dict (it may be ``None``
+    for a backend without a cost model)."""
+    return dict(compiled.cost_analysis() or {})
 
-    Older jaxlibs return a one-element list of dicts (per device
-    program), newer ones a plain dict; both collapse to a dict here so
-    callers can index ``["flops"]`` unconditionally.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost or {})
 
 _ELTWISE_1 = {"add", "subtract", "multiply", "divide", "maximum", "minimum",
               "and", "or", "xor", "compare", "select", "negate", "abs",

@@ -64,8 +64,9 @@ MAX_FUSED_ELEMS = 1 << 27
 
 def _build(*, d_t, task_sfc, d_p, proc_sfc, longest_dim, weighted,
            tnum, pnum, t_sel, p_sel, npts_bt, nbt_b, npts_bp, nbp_b,
-           tab_b, dims, wrap, core_dims, objective, traffic, score_kind,
-           ne, ne_b, nb_b, ncols, tile, interpret, refine):
+           tab_b, cut_bt, cut_bp, bits_t, bits_p, dims, wrap, core_dims,
+           objective, traffic, score_kind, ne, ne_b, nb_b, ncols, tile,
+           interpret, refine):
     """The traced body of one fused program (all kwargs static).
 
     ``refine`` is ``None`` (sweep only) or a static ``(rounds, top,
@@ -75,19 +76,14 @@ def _build(*, d_t, task_sfc, d_p, proc_sfc, longest_dim, weighted,
     ``repro.hier.refine.refine_swaps`` decision for decision), and only
     the refined cluster -> router permutation returns to host.
     """
-    from repro.core.orderings import hilbert_bits
-
     # Hilbert has no cut dimensions: canonicalise longest_dim so the
-    # knob cannot fragment the partition-engine compile cache, and
-    # derive the static quantisation resolution both engines unroll over
+    # knob cannot fragment the partition-engine compile cache
     ld_t = True if task_sfc == "H" else longest_dim
     ld_p = True if proc_sfc == "H" else longest_dim
-    bits_t = hilbert_bits(tnum, d_t) if task_sfc == "H" else 0
-    bits_p = hilbert_bits(pnum, d_p) if proc_sfc == "H" else 0
     eng_t = _pj._engine(d_t, task_sfc, ld_t, weighted,
-                        npts_bt, nbt_b, tab_b, bits_t)
+                        npts_bt, nbt_b, tab_b, cut_bt, bits_t)
     eng_p = _pj._engine(d_p, proc_sfc, ld_p, False,
-                        npts_bp, nbp_b, tab_b, bits_p)
+                        npts_bp, nbp_b, tab_b, cut_bp, bits_p)
     if score_kind == "jax":
         score_fn = metrics_jax._scorer(dims, wrap, core_dims, traffic,
                                        ne_b, nb_b)
@@ -145,13 +141,12 @@ def _build(*, d_t, task_sfc, d_p, proc_sfc, longest_dim, weighted,
                             jnp.where(s[j] > t[j] + 1e-12, False, res))
         return res
 
-    def run(cols_t, sdo_t, w_t, cols_p, sdo_p, w_p1, tab, edges, ew,
-            ew64, acoords, bw):
+    def run(args_t, args_p, edges, ew, ew64, acoords, bw):
         # --- stage 2: both partitions (inner jit calls inline) ---------
-        mu_t = eng_t(cols_t, sdo_t, w_t, tab, jnp.int32(tnum),
-                     jnp.int32(nut), jnp.int32(pnum))[:, :tnum]
-        mu_p = eng_p(cols_p, sdo_p, w_p1, tab, jnp.int32(pnum),
-                     jnp.int32(nup), jnp.int32(pnum))[:nup, :pnum]
+        mu_t = eng_t(*args_t, jnp.int32(tnum), jnp.int32(nut),
+                     jnp.int32(pnum))[:, :tnum]
+        mu_p = eng_p(*args_p, jnp.int32(pnum), jnp.int32(nup),
+                     jnp.int32(pnum))[:nup, :pnum]
 
         # --- stage 3: vectorised GETMAPPINGARRAYS ----------------------
         # (mirrors map_candidates' part_to_proc / mu_t gathers)
@@ -167,20 +162,21 @@ def _build(*, d_t, task_sfc, d_p, proc_sfc, longest_dim, weighted,
             """(B, nobj) f64 objective matrix for a padded stack batch
             (the same column builder the sweep's winner selection and
             the host CandidateSearch lexsort use)."""
-            src = cs_padded[:, e0, :ncols]
-            dst = cs_padded[:, e1, :ncols]
             if score_kind == "jax":
-                ev = fn(src, dst, ew, bw)
+                ev = fn(cs_padded[:, e0, :ncols], cs_padded[:, e1, :ncols],
+                        ew, bw)
                 wh, th = ev["weighted_hops"], ev["total_hops"]
                 data, lat = ev.get("data_max"), ev.get("latency_max")
             else:
-                args = [src, dst, ew.reshape(-1, 1)]
+                # the kernel takes messages on lanes: (B, ncols, E)
+                cst = jnp.swapaxes(cs_padded[:, :, :ncols], 1, 2)
+                args = [cst[:, :, e0], cst[:, :, e1], ew.reshape(1, -1)]
                 if traffic:
                     args.append(bw)
                 outf, outi = fn(*args)
-                wh, th = outf[:, 0], outi[:, 0]
-                data = outf[:, 1] if traffic else None
-                lat = outf[:, 2] if traffic else None
+                wh, th = outf[:, 0, 0], outi[:, 0, 0]
+                data = outf[:, 0, 1] if traffic else None
+                lat = outf[:, 0, 2] if traffic else None
 
             def col(key):
                 if key == "weighted_hops":
@@ -400,8 +396,9 @@ def _build(*, d_t, task_sfc, d_p, proc_sfc, longest_dim, weighted,
 @functools.lru_cache(maxsize=None)
 def _program(d_t, task_sfc, d_p, proc_sfc, longest_dim, weighted,
              tnum, pnum, t_sel, p_sel, npts_bt, nbt_b, npts_bp, nbp_b,
-             tab_b, dims, wrap, core_dims, objective, traffic, score_kind,
-             ne, ne_b, nb_b, ncols, tile, interpret, refine):
+             tab_b, cut_bt, cut_bp, bits_t, bits_p, dims, wrap, core_dims,
+             objective, traffic, score_kind, ne, ne_b, nb_b, ncols, tile,
+             interpret, refine):
     """One jitted fused program per (pipeline knobs, shape bucket).
 
     Every cache entry sees exactly one input shape set, so the
@@ -412,7 +409,8 @@ def _program(d_t, task_sfc, d_p, proc_sfc, longest_dim, weighted,
         d_t=d_t, task_sfc=task_sfc, d_p=d_p, proc_sfc=proc_sfc,
         longest_dim=longest_dim, weighted=weighted, tnum=tnum, pnum=pnum,
         t_sel=t_sel, p_sel=p_sel, npts_bt=npts_bt, nbt_b=nbt_b,
-        npts_bp=npts_bp, nbp_b=nbp_b, tab_b=tab_b, dims=dims, wrap=wrap,
+        npts_bp=npts_bp, nbp_b=nbp_b, tab_b=tab_b, cut_bt=cut_bt,
+        cut_bp=cut_bp, bits_t=bits_t, bits_p=bits_p, dims=dims, wrap=wrap,
         core_dims=core_dims, objective=objective, traffic=traffic,
         score_kind=score_kind, ne=ne, ne_b=ne_b, nb_b=nb_b, ncols=ncols,
         tile=tile, interpret=interpret, refine=refine))
@@ -491,11 +489,11 @@ class FusedSweep:
         p_sel = tuple(p_of[p] for p in p_perms)
         task_sfc, proc_sfc = pipe._sfc_pair(td, pd)
 
-        cols_t, sdo_t, w_t, tab, npts_bt, nbt_b, tab_b = _pj._prepare(
-            tc, pnum, np.array(ut, dtype=np.int64), task_weights,
+        args_t, (npts_bt, nbt_b, tab_b, cut_bt, bits_t) = _pj._prepare(
+            tc, pnum, task_sfc, np.array(ut, dtype=np.int64), task_weights,
             cfg.uneven_prime)
-        cols_p, sdo_p, w_p1, _, npts_bp, nbp_b, _ = _pj._prepare(
-            pc, pnum, np.array(up, dtype=np.int64), None,
+        args_p, (npts_bp, nbp_b, _, cut_bp, bits_p) = _pj._prepare(
+            pc, pnum, proc_sfc, np.array(up, dtype=np.int64), None,
             cfg.uneven_prime)
 
         edges = jnp.asarray(
@@ -520,32 +518,25 @@ class FusedSweep:
             bw = tuple(jnp.asarray(machine.bw_field(k), dtype=jnp.float32)
                        for k in range(nd)) if traffic else ()
         else:
-            if traffic:
-                inv = np.concatenate([
-                    1.0 / np.asarray(
-                        machine.bw(k, np.arange(int(machine.dims[k]))),
-                        dtype=np.float64)
-                    for k in range(nd)]) if nd else np.zeros(0)
-                bw = jnp.asarray(inv.reshape(-1, 1), dtype=jnp.float32)
-            else:
-                bw = ()
+            bw = (jnp.asarray(_mapscore.inv_bandwidth(machine))
+                  if traffic else ())
 
         misses0 = _program.cache_info().misses
         fn = _program(td, task_sfc, pd, proc_sfc, bool(cfg.longest_dim),
                       task_weights is not None, tnum, pnum, t_sel, p_sel,
-                      npts_bt, nbt_b, npts_bp, nbp_b, tab_b,
+                      npts_bt, nbt_b, npts_bp, nbp_b, tab_b, cut_bt,
+                      cut_bp, bits_t, bits_p,
                       tuple(int(x) for x in machine.dims),
                       tuple(bool(x) for x in machine.wrap),
                       machine.core_dims, tuple(objective), traffic, kind,
                       ne, ne_b, nb_b, ncols, tile, bool(interpret),
                       refine_t)
-        obs.annotate(
-            score_backend=kind, candidates=ncand,
-            compile_cache=("miss"
-                           if _program.cache_info().misses > misses0
-                           else "hit"))
-        out = fn(cols_t, sdo_t, w_t, cols_p, sdo_p, w_p1, tab, edges,
-                 ew, ew64, acoords, bw)
+        miss = _program.cache_info().misses > misses0
+        obs.annotate(score_backend=kind, candidates=ncand,
+                     compile_cache="miss" if miss else "hit")
+        if miss and interpret and kind == "pallas":
+            obs.counter("pallas.interpret_compiles")
+        out = fn(args_t, args_p, edges, ew, ew64, acoords, bw)
         best_i, t2p, scores, ok = out[:4]
         if not bool(ok):
             return None  # a part got no processor: unfused path raises

@@ -38,21 +38,27 @@ def machine_for(devices=None, *, pods: int = 1, side: int = 16):
 
 
 def device_coords(devices, machine) -> np.ndarray:
-    """Physical coordinates per device.
+    """Physical coordinates per device, inside ``machine``.
 
-    Real TPUs expose ``device.coords`` (+ slice_index for multislice);
-    fake CPU devices get machine coordinates in enumeration order.
+    Real TPUs expose ``device.coords`` (+ slice_index for multislice):
+    the chip (x, y) is shifted so the slice starts at the origin of the
+    machine's torus, and a chip that then falls outside the machine is
+    an error.  Fake CPU devices get machine coordinates in enumeration
+    order.
     """
-    coords = []
     have_real = all(hasattr(d, "coords") and d.platform == "tpu"
                     for d in devices)
-    if have_real:  # pragma: no cover - no TPU in this container
-        for d in devices:
-            c = list(d.coords)[:2]
-            pod = getattr(d, "slice_index", 0)
-            coords.append([pod] + c if machine.ndim == 3 else c)
-        return np.asarray(coords, float)
-    return block_allocation(machine).coords[: len(devices)].astype(float)
+    if not have_real:
+        return block_allocation(machine).coords[: len(devices)].astype(float)
+    xy = np.asarray([list(d.coords)[:2] for d in devices], dtype=np.int64)
+    xy -= xy.min(axis=0)
+    if machine.ndim == 3:
+        pod = np.asarray([getattr(d, "slice_index", 0) for d in devices])
+        xy = np.concatenate([pod[:, None], xy], axis=1)
+    if (xy >= np.asarray(machine.dims)).any():
+        raise ValueError(f"device coordinates {xy.tolist()} fall outside "
+                         f"the {tuple(machine.dims)} machine")
+    return xy.astype(float)
 
 
 def topology_mesh(axis_sizes: tuple[int, ...], axis_names: tuple[str, ...],

@@ -56,3 +56,40 @@ def test_candidate_mapping_not_worse_on_collective_term():
                            {"default": identity_mapping(g, alloc),
                             "mapped": best})
     assert res["mapped"] <= res["default"] * 1.001
+
+
+class _Chip:
+    """A stand-in for a TPU device: the attributes ``device_coords``
+    reads."""
+
+    platform = "tpu"
+
+    def __init__(self, coords, slice_index=0):
+        self.coords = coords
+        self.slice_index = slice_index
+
+
+def test_device_coords_real_chips_start_at_origin():
+    """Real-coordinate branch: a 2x2 slice cut at an offset of a pod
+    lands at the origin of the machine's torus (z and the core index
+    are ignored); multislice adds the slice as the leading dim."""
+    from repro.meshmap.device_mesh import device_coords, machine_for
+
+    chips = [_Chip((4, 6, 0)), _Chip((5, 6, 0)), _Chip((4, 7, 0)),
+             _Chip((5, 7, 0))]
+    xy = device_coords(chips, machine_for(side=2))
+    assert xy.tolist() == [[0, 0], [1, 0], [0, 1], [1, 1]]
+    pods = [_Chip((c.coords[0], c.coords[1], 0), slice_index=s)
+            for s in (0, 1) for c in chips[:2]]
+    assert device_coords(pods, machine_for(pods=2, side=2)).tolist() == \
+        [[0, 0, 0], [0, 1, 0], [1, 0, 0], [1, 1, 0]]
+
+
+def test_device_coords_outside_machine_raise():
+    import pytest
+
+    from repro.meshmap.device_mesh import device_coords, machine_for
+
+    chips = [_Chip((0, 0, 0)), _Chip((3, 0, 0))]
+    with pytest.raises(ValueError, match="outside"):
+        device_coords(chips, machine_for(side=2))

@@ -82,6 +82,48 @@ def test_pallas_scoring_parity_all_keys(mi):
     assert np.array_equal(a["average_hops"], b["average_hops"])
 
 
+@pytest.mark.parametrize("traffic", [False, True], ids=["hops", "traffic"])
+def test_pallas_kernel_interpret_under_x64(traffic):
+    """The kernel is traced with x64 off even when the process has it on
+    (the device partitioner's setting): same numbers as the numpy
+    reference, 32-bit (nb, 8, 128) output tiles."""
+    import jax
+
+    from repro.kernels.mapscore.kernel import OUT_TILE, mapscore_call
+
+    machine = MACHINES[0]
+    stack, edges, w = _random_problem(machine, 5, nb=3)
+    ref = evaluate_candidates(machine, edges, w, stack, traffic=traffic,
+                              backend="numpy")
+    ncols = machine.ndim
+    cs = np.swapaxes(stack[:, :, :ncols], 1, 2).astype(np.int32)
+    ne_b = 128
+    src = mops.pad_axis(cs[:, :, edges[:, 0]], ne_b, axis=2)
+    dst = mops.pad_axis(cs[:, :, edges[:, 1]], ne_b, axis=2)
+    wp = mops.pad_axis(w.astype(np.float32).reshape(1, -1), ne_b, axis=1)
+    args = [src, dst, wp]
+    if traffic:
+        args.append(mops.inv_bandwidth(machine))
+    with jax.enable_x64(True):
+        outf, outi = mapscore_call(
+            *args, dims=tuple(int(x) for x in machine.dims),
+            wrap=tuple(bool(x) for x in machine.wrap),
+            core_dims=machine.core_dims, traffic=traffic, tile=128,
+            interpret=True)
+    assert outf.shape == outi.shape == (3,) + OUT_TILE
+    assert outf.dtype == np.float32 and outi.dtype == np.int32
+    outf, outi = np.array(outf), np.array(outi)
+    assert np.array_equal(outi[:, 0, 0], ref["total_hops"])
+    assert np.allclose(outf[:, 0, 0], ref["weighted_hops"], rtol=1e-5)
+    if traffic:
+        assert np.allclose(outf[:, 0, 1], ref["data_max"], rtol=1e-4)
+        assert np.allclose(outf[:, 0, 2], ref["latency_max"], rtol=1e-4)
+    # only the metric lanes of row 0 carry values
+    outf[:, 0, :3] = 0.0
+    outi[:, 0, 0] = 0
+    assert not outf.any() and not outi.any()
+
+
 def test_pallas_wrapped_torus_edges():
     """Messages crossing the wraparound seam in both directions."""
     machine = make_machine((6, 5), wrap=(True, True))

@@ -104,6 +104,43 @@ def test_padded_bucket_tails():
         _assert_jax_equiv(coords, 32, "FZ", weights=rng.random(n))
 
 
+@pytest.mark.parametrize("sfc", SFCS)
+def test_signed_zeros_tie_like_numpy(sfc):
+    """Coordinates enter as dense ranks: -0.0 and 0.0 share a rank, so
+    they tie exactly as numpy's comparisons make them, flips or not."""
+    rng = np.random.default_rng(41)
+    coords = rng.integers(-2, 3, size=(120, 2)).astype(float)
+    coords[rng.random(coords.shape) < 0.3] = -0.0
+    coords[rng.random(coords.shape) < 0.3] = 0.0
+    _assert_jax_equiv(coords, 12, sfc)
+
+
+@pytest.mark.parametrize("n,nparts,uneven", [(1000, 37, False),
+                                             (1000, 37, True),
+                                             (32856, 32856, False),
+                                             (7, 16, False)])
+def test_cut_table_matches_oracle_recursion(n, nparts, uneven):
+    """The host cut table holds exactly the oracle's unit-weight cuts
+    for every (size, parts) segment the recursion reaches."""
+    from repro.core.orderings import _split_counts
+    from repro.core.partition import _uniform_cuts
+
+    tab = partition_jax._cut_table(n, nparts, uneven)
+    rows = {(int(s_), int(p_)): int(k) for s_, p_, k in tab}
+    todo = [(n, nparts)]
+    seen = set()
+    while todo:
+        size, p = todo.pop()
+        if size <= 1 or p <= 1 or (size, p) in seen:
+            continue
+        seen.add((size, p))
+        npl = _split_counts(p, uneven)[0]
+        k = int(_uniform_cuts(np.array([size]), np.array([npl / p]))[0])
+        assert rows[(size, p)] == k
+        todo += [(k, npl), (size - k, p - npl)]
+    assert set(rows) == seen
+
+
 # ---------------------------------------------------------------------------
 # device Hilbert: Skilling's transpose as a batched jitted kernel
 # ---------------------------------------------------------------------------
