@@ -148,9 +148,8 @@ def _cut_table(n: int, nparts: int, uneven_prime: bool) -> np.ndarray:
             rows[(size, p)] = k
             nxt |= {(k, npl), (size - k, p - npl)}
         todo = nxt
-    tab = np.array([(s_, p_, k) for (s_, p_), k in sorted(rows.items())],
-                   dtype=np.int32).reshape(-1, 3)
-    return tab
+    return np.array([(s_, p_, k) for (s_, p_), k in sorted(rows.items())],
+                    dtype=np.int32).reshape(-1, 3)
 
 
 # ---------------------------------------------------------------------------
