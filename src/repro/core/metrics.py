@@ -235,12 +235,11 @@ _BACKEND_CHAIN = {
 _JAX_EVAL = False     # memoised import: False = untried, None = unavailable
 _PALLAS_EVAL = False  # likewise for the Pallas mapscore kernel
 
-# Only a missing package disables an accelerator backend.  Anything
-# else (a genuine bug, a broken device that raises RuntimeError or
-# OSError while initialising) must PROPAGATE — the serve layer's
+# Only a missing package (ImportError) disables an accelerator backend.
+# Anything else (a genuine bug, a broken device that raises RuntimeError
+# or OSError while initialising) must PROPAGATE — the serve layer's
 # degradation ladder handles those per-request instead of silently
 # pinning the process to a slower rung.
-_IMPORT_FAILURES = (ImportError,)
 
 # cause of each unavailable backend (repr), for the one-shot warning
 _FALLBACK_CAUSE: dict[str, str] = {}
@@ -269,7 +268,7 @@ def _jax_evaluator():
         try:
             from . import metrics_jax
             _JAX_EVAL = metrics_jax.evaluate_candidates_jax
-        except _IMPORT_FAILURES as e:  # pragma: no cover - jax in image
+        except ImportError as e:  # pragma: no cover - jax in image
             _JAX_EVAL = None
             _FALLBACK_CAUSE["jax"] = repr(e)
     return _JAX_EVAL
@@ -283,7 +282,7 @@ def _pallas_evaluator():
         try:
             from repro.kernels.mapscore import ops as mapscore_ops
             _PALLAS_EVAL = mapscore_ops.evaluate_candidates_pallas
-        except _IMPORT_FAILURES as e:  # pragma: no cover - jax in image
+        except ImportError as e:  # pragma: no cover - jax in image
             _PALLAS_EVAL = None
             _FALLBACK_CAUSE["pallas"] = repr(e)
     return _PALLAS_EVAL

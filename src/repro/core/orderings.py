@@ -58,11 +58,10 @@ _PARTITION_CHAIN = {"numpy": ("numpy",), "jax": ("jax", "numpy")}
 # module object = ready
 _JAX_PART = False
 
-# Only a missing package disables the device partition engine; a
-# broken device and runtime faults must propagate to the caller (the
-# serve layer's degradation ladder owns per-request recovery).  Mirrors
-# ``metrics._IMPORT_FAILURES``.
-_IMPORT_FAILURES = (ImportError,)
+# Only a missing package (ImportError) disables the device partition
+# engine; a broken device and runtime faults must propagate to the
+# caller (the serve layer's degradation ladder owns per-request
+# recovery), as in the score-backend chain of :mod:`repro.core.metrics`.
 
 _JAX_PART_CAUSE: str | None = None
 _WARNED_FALLBACK = False
@@ -86,7 +85,7 @@ def _jax_partition_module():
         try:
             from . import partition_jax
             _JAX_PART = partition_jax
-        except _IMPORT_FAILURES as e:  # pragma: no cover - jax in image
+        except ImportError as e:  # pragma: no cover - jax in image
             _JAX_PART = None
             _JAX_PART_CAUSE = repr(e)
     return _JAX_PART
