@@ -43,6 +43,19 @@ from .machine import Machine
 MSG_BUCKET_MIN = 128  # smallest padded message-count bucket
 
 
+def _profiler_annotation(name: str):
+    """The jax profiler's host event for a ``repro.obs`` span while a
+    profiler session runs; nothing otherwise."""
+    if jax.profiler.TraceAnnotation.is_enabled():
+        return jax.profiler.TraceAnnotation(name)
+    return None
+
+
+# every device path of the mapper imports this module: from here on
+# each span also lands in any jax profiler trace, on its clock
+obs.set_annotation(_profiler_annotation)
+
+
 def bucket_size(n: int, lo: int = MSG_BUCKET_MIN) -> int:
     """Next power of two >= max(n, lo) — the padded shape every dynamic
     axis is bucketed to before entering jit (zero-weight padding is

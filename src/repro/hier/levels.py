@@ -304,29 +304,34 @@ def map_hierarchical(
                         machine, aggs[i].coarse, m_coords[i], cur, lvl,
                         objective=pipe.search.objective,
                         score_backend=cfg.score_backend)
-                if i > 0:
-                    # one-level expansion as a per-group GEOMETRIC
-                    # match (paper Alg. 1's consistent-ordering trick):
-                    # assign_cores deals Hilbert-ordered children onto
-                    # member units in input order, so presenting each
-                    # group's units in THEIR intra-group Hilbert order
-                    # aligns both curves.  (The i == 0 core expansion
-                    # keeps allocation order: cores of a node are hop-0,
-                    # order cannot change a metric — and depth-2 stays
-                    # bit-identical to the legacy path.)
-                    child = aggs[i - 1].coarse.coords
-                    if tperm is not None and child.shape[1] == len(tperm):
-                        child = child[:, tperm]
-                    units = m_coords[i - 1].astype(np.float64)
-                    if pperm is not None and units.shape[1] == len(pperm):
-                        units = units[:, pperm]
-                    sub = np.lexsort((hilbert_key(units), m_member[i]))
-                    cur = sub[assign_cores(
-                        aggs[i].labels, cur, m_member[i][sub],
-                        child, len(m_coords[i]))]
-                else:
-                    t2p = assign_cores(aggs[0].labels, cur, core_router,
-                                       tc, nrouters)
+                with obs.span("pipeline.expand", level=i + 1,
+                              points=int(len(aggs[i].labels))):
+                    if i > 0:
+                        # one-level expansion as a per-group GEOMETRIC
+                        # match (paper Alg. 1's consistent-ordering
+                        # trick): assign_cores deals Hilbert-ordered
+                        # children onto member units in input order, so
+                        # presenting each group's units in THEIR
+                        # intra-group Hilbert order aligns both curves.
+                        # (The i == 0 core expansion keeps allocation
+                        # order: cores of a node are hop-0, order cannot
+                        # change a metric — and depth-2 stays
+                        # bit-identical to the legacy path.)
+                        child = aggs[i - 1].coarse.coords
+                        if (tperm is not None
+                                and child.shape[1] == len(tperm)):
+                            child = child[:, tperm]
+                        units = m_coords[i - 1].astype(np.float64)
+                        if (pperm is not None
+                                and units.shape[1] == len(pperm)):
+                            units = units[:, pperm]
+                        sub = np.lexsort((hilbert_key(units), m_member[i]))
+                        cur = sub[assign_cores(
+                            aggs[i].labels, cur, m_member[i][sub],
+                            child, len(m_coords[i]))]
+                    else:
+                        t2p = assign_cores(aggs[0].labels, cur,
+                                           core_router, tc, nrouters)
             timings["refine_s"] += sp.duration_s
             level_stats[i]["refine_s"] = sp.duration_s
             level_stats[i]["refine_accepted"] = \

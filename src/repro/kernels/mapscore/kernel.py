@@ -285,6 +285,7 @@ def mapscore_call(src, dst, w, inv_bw=None, *, dims, wrap, core_dims,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="mapscore",
     )
     # traced (body and index maps) with x64 off: its literals are then
     # 32-bit here and inside the fused program of an x64 process

@@ -44,6 +44,7 @@ import numpy as np
 from repro import faults, obs
 from repro.core import partition_jax as _pj  # noqa: F401  (enables x64)
 
+import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax import lax  # noqa: E402
 
@@ -142,19 +143,24 @@ def _build(*, d_t, task_sfc, d_p, proc_sfc, longest_dim, weighted,
         return res
 
     def run(args_t, args_p, edges, ew, ew64, acoords, bw):
+        # each stage runs under a named scope (partition / match /
+        # score / refine): the scope path is in every operation's
+        # metadata, so a device trace can split the program by stage
         # --- stage 2: both partitions (inner jit calls inline) ---------
-        mu_t = eng_t(*args_t, jnp.int32(tnum), jnp.int32(nut),
-                     jnp.int32(pnum))[:, :tnum]
-        mu_p = eng_p(*args_p, jnp.int32(pnum), jnp.int32(nup),
-                     jnp.int32(pnum))[:nup, :pnum]
+        with jax.named_scope("partition"):
+            mu_t = eng_t(*args_t, jnp.int32(tnum), jnp.int32(nut),
+                         jnp.int32(pnum))[:, :tnum]
+            mu_p = eng_p(*args_p, jnp.int32(pnum), jnp.int32(nup),
+                         jnp.int32(pnum))[:nup, :pnum]
 
         # --- stage 3: vectorised GETMAPPINGARRAYS ----------------------
         # (mirrors map_candidates' part_to_proc / mu_t gathers)
-        ptp = jnp.full((nup, pnum), -1, dtype=jnp.int32)
-        ptp = ptp.at[jnp.arange(nup)[:, None], mu_p].set(
-            jnp.arange(pnum, dtype=jnp.int32)[None, :])
-        ok = jnp.min(ptp) >= 0
-        t2p = jnp.take_along_axis(ptp[p_sel_a], mu_t[t_sel_a], axis=1)
+        with jax.named_scope("match"):
+            ptp = jnp.full((nup, pnum), -1, dtype=jnp.int32)
+            ptp = ptp.at[jnp.arange(nup)[:, None], mu_p].set(
+                jnp.arange(pnum, dtype=jnp.int32)[None, :])
+            ok = jnp.min(ptp) >= 0
+            t2p = jnp.take_along_axis(ptp[p_sel_a], mu_t[t_sel_a], axis=1)
 
         e0, e1 = edges[:, 0], edges[:, 1]
 
@@ -191,18 +197,25 @@ def _build(*, d_t, task_sfc, d_p, proc_sfc, longest_dim, weighted,
             return jnp.stack([col(k) for k in objective], axis=1)
 
         # --- stage 4: score + select -----------------------------------
-        cs = acoords[t2p]                          # (ncand, tnum, ndim)
-        cs = jnp.pad(cs, ((0, nb_b - ncand), (0, 0), (0, 0)))
-        scores = batch_cols(cs, score_fn)[:ncand]
-        keys = tuple(scores[:, j]
-                     for j in reversed(range(scores.shape[1])))
-        best_i = jnp.lexsort(keys)[0].astype(jnp.int32)
+        with jax.named_scope("score"):
+            cs = acoords[t2p]                      # (ncand, tnum, ndim)
+            cs = jnp.pad(cs, ((0, nb_b - ncand), (0, 0), (0, 0)))
+            scores = batch_cols(cs, score_fn)[:ncand]
+            keys = tuple(scores[:, j]
+                         for j in reversed(range(scores.shape[1])))
+            best_i = jnp.lexsort(keys)[0].astype(jnp.int32)
         if refine is None:
             return best_i, t2p[best_i], scores, ok
+        with jax.named_scope("refine"):
+            return refine_stage(best_i, t2p, scores, ok, edges, ew64,
+                                acoords, batch_cols)
 
-        # --- stage 5: fused swap refinement ----------------------------
-        # (mirrors hier.refine.refine_swaps decision for decision; the
-        # host pass is the oracle — see tests/test_hier.py)
+    # --- stage 5: fused swap refinement --------------------------------
+    # (mirrors hier.refine.refine_swaps decision for decision; the host
+    # pass is the oracle — see tests/test_hier.py)
+    def refine_stage(best_i, t2p, scores, ok, edges, ew64, acoords,
+                     batch_cols):
+        e0, e1 = edges[:, 0], edges[:, 1]
         rc = acoords.astype(jnp.int64)            # (pnum, ncols) rows
         c2r0 = t2p[best_i]                        # cluster -> router
         r2c0 = jnp.full((pnum,), -1, jnp.int32).at[c2r0].set(
@@ -536,32 +549,40 @@ class FusedSweep:
                      compile_cache="miss" if miss else "hit")
         if miss and interpret and kind == "pallas":
             obs.counter("pallas.interpret_compiles")
-        out = fn(args_t, args_p, edges, ew, ew64, acoords, bw)
-        best_i, t2p, scores, ok = out[:4]
-        if not bool(ok):
-            return None  # a part got no processor: unfused path raises
-        best_i = int(best_i)
+        # from the call of the program to the last read of its outputs:
+        # upload of the partition inputs, the device run, read-back
+        with obs.span("fused.execute", candidates=ncand,
+                      points=int(tnum + pnum)):
+            out = fn(args_t, args_p, edges, ew, ew64, acoords, bw)
+            best_i, t2p, scores, ok = out[:4]
+            if not bool(ok):
+                return None  # a part got no processor: unfused path raises
+            best_i = int(best_i)
+            t2p = np.asarray(t2p, dtype=np.int32)
+            score = float(np.asarray(scores)[best_i][0])
+            if refine_t is not None:
+                hist, hist_len, acc_t, ev_t = out[4:]
+                hist_len = int(hist_len)
+                hist = np.asarray(hist)[:hist_len]
+                acc_t, ev_t = int(acc_t), int(ev_t)
         c = cands[best_i]
         best = MappingResult(
-            np.asarray(t2p, dtype=np.int32),
+            t2p,
             rotation=(tuple(c.task_perm or ()), tuple(c.proc_perm or ())))
-        best.score = float(np.asarray(scores)[best_i][0])
+        best.score = score
         best.stats.update(fused=True, fused_score_backend=kind,
                           winner_index=best_i)
         if refine_t is not None:
-            hist, hist_len, acc_t, ev_t = out[4:]
-            hist_len = int(hist_len)
-            history = [tuple(float(x) for x in row)
-                       for row in np.asarray(hist)[:hist_len]]
+            history = [tuple(float(x) for x in row) for row in hist]
             best.stats.update(
                 fused_refine=True,
                 refine_rounds_run=hist_len - 1,
-                refine_accepted=int(acc_t),
-                refine_evaluated=int(ev_t),
+                refine_accepted=acc_t,
+                refine_evaluated=ev_t,
                 refine_history=history,
                 refine_initial=history[0][0],
                 refine_final=history[-1][0])
             best.score = history[-1][0]
             obs.annotate(refine_rounds=hist_len - 1,
-                         refine_accepted=int(acc_t))
+                         refine_accepted=acc_t)
         return best

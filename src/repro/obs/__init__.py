@@ -26,7 +26,7 @@ from .metrics import (REGISTRY, counter, gauge, instrument_compile_cache,
                       register_provider, snapshot, span_rollup)
 from .trace import (TRACER, Span, Tracer, add_sink, annotate, attach,
                     current_span, finished, format_tree, remove_sink,
-                    reset, span, span_tree)
+                    reset, set_annotation, span, span_tree)
 
 # arm the process-wide JSONL event log when REPRO_TRACE names a path
 _ENV_SINK = install_env_sink()
@@ -38,6 +38,6 @@ __all__ = [
     "instrument_compile_cache", "jax_profile", "observe",
     "prometheus_text", "read_jsonl", "register_cache",
     "register_object", "register_provider", "remove_sink", "reset",
-    "snapshot", "span", "span_rollup", "span_tree",
+    "set_annotation", "snapshot", "span", "span_rollup", "span_tree",
     "write_chrome_trace",
 ]

@@ -24,8 +24,15 @@ module replaces them with SPANS:
   threads (the serve layer's deadline worker) re-parents explicitly
   with :func:`attach`.
 
+- A layer that may import an accelerator runtime installs an
+  ANNOTATION factory (:func:`set_annotation`): each span then also
+  opens what the factory returns for its name, such as a host event in
+  the jax profiler's trace while a profiler session runs, so the spans
+  sit on the profiler's own clock beside the device's operations.
+
 The module is stdlib-only and allocation-light: an unsampled process
-pays one contextvar read per span plus a deque append on exit.
+pays one contextvar read per span plus a deque append on exit, and one
+call of the annotation factory where one is installed.
 """
 
 from __future__ import annotations
@@ -45,6 +52,18 @@ MAX_FINISHED = 4096
 _IDS = itertools.count(1)
 _CURRENT: contextvars.ContextVar = contextvars.ContextVar(
     "repro_obs_span", default=None)
+# factory(name) -> a context manager held open for the span's life, or
+# None; see set_annotation
+_ANNOTATION = None
+
+
+def set_annotation(factory) -> None:
+    """Install ``factory(name)``, called as every span opens.  What it
+    returns (a context manager, or ``None`` for nothing) is entered
+    with the span and exited as the span closes, on the same thread.
+    ``set_annotation(None)`` uninstalls it."""
+    global _ANNOTATION
+    _ANNOTATION = factory
 
 
 class Span:
@@ -126,6 +145,9 @@ class Tracer:
             sp = Span(name, uuid.uuid4().hex[:16], None, **attrs)
         else:
             sp = Span(name, parent.trace_id, parent.span_id, **attrs)
+        ann = _ANNOTATION(name) if _ANNOTATION is not None else None
+        if ann is not None:
+            ann.__enter__()
         token = _CURRENT.set(sp)
         try:
             yield sp
@@ -134,6 +156,8 @@ class Tracer:
             raise
         finally:
             sp.t1 = time.perf_counter()
+            if ann is not None:
+                ann.__exit__(None, None, None)
             _CURRENT.reset(token)
             self._finish(sp)
 

@@ -9,6 +9,7 @@ refinement, and the Mapper / meshmap wiring."""
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import (Mapper, MapperConfig, TaskGraph, evaluate,
                         gemini_xk7, identity_mapping, logical_mesh_graph,
                         make_machine, sfc_allocation, stencil_graph,
@@ -389,6 +390,72 @@ def test_fused_refinement_ladder_unfused_rung_bit_identical():
     assert not unfused.stats.get("fused_refine")
     assert np.array_equal(full.task_to_proc, unfused.task_to_proc)
     assert full.stats["refine_history"] == unfused.stats["refine_history"]
+
+
+def _fused_node_pipeline(sfc="FZ"):
+    return MappingPipeline(PipelineConfig(
+        sfc=sfc, rotations=4, hierarchy=HierarchySpec.node(),
+        partition_backend="jax", score_backend="jax"))
+
+
+def test_node_map_spans_name_contraction_expansion_and_execution():
+    """Coarsening's contraction, the refine stage's expansion and the
+    fused program's run get spans of their own, under the stage spans
+    they split; the stage timings and per-level stats keep their
+    schema and still read the stage spans."""
+    m, alloc, g = _fused_refine_case()
+    res = _fused_node_pipeline().map(g, alloc)
+    assert res.stats.get("fused_refine") is True
+    spans = obs.finished(res.stats["trace_id"])
+    by_id = {s.span_id: s for s in spans}
+
+    def parents(name):
+        return [by_id[s.parent_id].name for s in spans if s.name == name]
+
+    assert parents("pipeline.contract") == ["pipeline.coarsen"]
+    assert parents("partition.jax") == ["pipeline.coarsen"]
+    assert parents("pipeline.expand") == ["pipeline.refine"]
+    assert parents("fused.execute") == ["pipeline.fused"]
+    contract = next(s for s in spans if s.name == "pipeline.contract")
+    assert contract.attrs["points"] == g.n
+    t = res.stats["timings"]
+    assert set(t) == {"coarsen_s", "fused_s", "refine_s", "total_s"}
+    stage = {s.name: s.duration_s for s in spans}
+    assert t["coarsen_s"] == stage["pipeline.coarsen"]
+    assert t["refine_s"] == stage["pipeline.refine"]
+    assert t["fused_s"] == stage["pipeline.fused"]
+    assert [set(lv) for lv in res.stats["levels"]] == [{
+        "level", "name", "points", "clusters", "units", "coarsen_s",
+        "map_s", "refine_s", "refine_accepted", "refine_evaluated",
+        "refine_history"}]
+
+
+def test_fused_program_scopes_name_its_stages(monkeypatch):
+    """The fused program keeps its module name ``jit_run`` and runs
+    each stage under a named scope, which the lowered program's
+    locations carry."""
+    from repro.mapping import fused
+
+    lowered = []
+    real = fused._program
+
+    def spy(*key):
+        fn = real(*key)
+
+        def call(*args):
+            lowered.append(fn.lower(*args))
+            return fn(*args)
+        return call
+
+    spy.cache_info = real.cache_info
+    monkeypatch.setattr(fused, "_program", spy)
+    m, alloc, g = _fused_refine_case()
+    res = _fused_node_pipeline().map(g, alloc)
+    assert res.stats.get("fused_refine") is True
+    text = lowered[0].as_text(debug_info=True)
+    assert "module @jit_run " in text
+    for scope in ("partition", "match", "score", "refine"):
+        assert f"jit(run)/{scope}/" in text, scope
 
 
 # ---------------------------------------------------------------------------

@@ -12,7 +12,11 @@ schedule cannot perturb them.
 import dataclasses
 import functools
 import gc
+import glob
 import json
+import os
+import subprocess
+import sys
 import threading
 
 import numpy as np
@@ -445,6 +449,103 @@ def test_deadline_worker_spans_join_the_request_trace():
     assert any(s.name == "pipeline.map" for s in spans)
     threads = {s.thread for s in spans}
     assert len(threads) > 1  # rung ran off-thread yet stayed in-trace
+
+
+# ---------------------------------------------------------------------------
+# The annotation hook: spans in the jax profiler's trace
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def annotation_restored():
+    was = obs.trace._ANNOTATION
+    yield
+    obs.set_annotation(was)
+
+
+def test_annotation_factory_wraps_every_span(annotation_restored):
+    log = []
+
+    class Note:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    t = Tracer()
+    obs.set_annotation(Note)
+    with t.span("outer"):
+        with pytest.raises(ValueError):
+            with t.span("inner"):
+                raise ValueError
+    assert log == [("enter", "outer"), ("enter", "inner"),
+                   ("exit", "inner"), ("exit", "outer")]
+    # a factory may decline (no profiler session), and uninstalls
+    obs.set_annotation(lambda name: None)
+    with t.span("declined"):
+        pass
+    obs.set_annotation(None)
+    with t.span("plain"):
+        pass
+    assert len(log) == 4
+    assert [s.name for s in t.finished()] == ["inner", "outer",
+                                              "declined", "plain"]
+
+
+def test_fresh_import_of_obs_imports_no_jax():
+    src = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(obs.__file__))))
+    code = ("import sys, repro.obs; "
+            "print(sorted(m for m in sys.modules "
+            "if m == 'jax' or m.startswith('jax.')))")
+    out = subprocess.run([sys.executable, "-c", code], check=True,
+                         capture_output=True, text=True,
+                         env={**os.environ, "PYTHONPATH": src})
+    assert out.stdout.strip() == "[]"
+
+
+def test_spans_enter_the_jax_profiler_trace(tmp_path):
+    """While a profiler session runs, every span of a mapping is also a
+    host event of the same name in the profiler's trace, opened in the
+    same order, each inside its parent's event."""
+    if not _has_jax():
+        pytest.skip("the annotation factory is installed with jax")
+    import jax
+    from jax.profiler import ProfileData
+    from repro.core import gemini_xk7, sfc_allocation
+    from repro.mapping import MappingPipeline, PipelineConfig
+
+    m = gemini_xk7(dims=(8, 4, 4), cores_per_node=4)
+    alloc = sfc_allocation(m, 256, nfragments=2, seed=3)
+    g = stencil_graph((4, 8, 8))
+    pipe = MappingPipeline(PipelineConfig(
+        sfc="FZ", rotations=4, hierarchy=HierarchySpec.node(),
+        partition_backend="jax", score_backend="jax"))
+    pipe.map(g, alloc)  # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        res = pipe.map(g, alloc)
+    finally:
+        jax.profiler.stop_trace()
+    spans = sorted(obs.finished(res.stats["trace_id"]), key=lambda s: s.t0)
+    names = {s.name for s in spans}
+    assert {"pipeline.contract", "pipeline.expand",
+            "fused.execute"} <= names
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    events = sorted(
+        ((e.name, e.start_ns, e.start_ns + e.duration_ns)
+         for p in ProfileData.from_file(path).planes for ln in p.lines
+         for e in ln.events if e.name in names), key=lambda e: e[1])
+    assert [e[0] for e in events] == [s.name for s in spans]
+    event_of = {s.span_id: e for s, e in zip(spans, events)}
+    for s in spans:
+        if s.parent_id in event_of:
+            parent, child = event_of[s.parent_id], event_of[s.span_id]
+            assert parent[1] <= child[1] and child[2] <= parent[2], s.name
 
 
 # ---------------------------------------------------------------------------

@@ -403,3 +403,18 @@ def test_unfused_jax_partition_stage_timings():
     t = res.stats["timings"]
     assert {"partition_s", "score_s", "total_s"} <= set(t)
     assert res.stats["partition_backend"] == "jax"
+
+
+@pytest.mark.parametrize("sfc,module", [("FZ", "jit_partition_mj"),
+                                        ("H", "jit_partition_hilbert")])
+def test_engine_programs_are_named(sfc, module):
+    """The engine's own programs carry stable names, so a device trace
+    shows them as modules ``jit_partition_*`` (not ``jit__unknown``)."""
+    coords = np.random.default_rng(3).random((300, 3))
+    args, (npts_b, nb_b, tab_b, cut_b, bits) = partition_jax._prepare(
+        coords, 8, sfc, np.array([[0, 1, 2]]), None, False)
+    engine = partition_jax._engine(3, sfc, True, False, npts_b, nb_b,
+                                   tab_b, cut_b, bits)
+    text = engine.lower(*args, np.int32(300), np.int32(1),
+                        np.int32(8)).as_text()
+    assert f"module @{module} " in text

@@ -13,6 +13,7 @@ worker that runs this file loads the TPU library.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -88,6 +89,23 @@ def test_mapscore_compiles_for_v5e(one_chip, machine, traffic):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < TEMP_SHARE * HBM_BYTES, \
         mem.temp_size_in_bytes
+
+
+def test_mapscore_kernel_is_named_on_v5e(one_chip):
+    """The kernel's custom call is named ``mapscore`` in the compiled
+    program, the name its operation carries in a device trace."""
+    dims, wrap, core_dims, nb, ne = MACHINES["v5e16x16"]
+    args = [_spec((nb, len(dims), ne), np.int32, one_chip),
+            _spec((nb, len(dims), ne), np.int32, one_chip),
+            _spec((1, ne), np.float32, one_chip)]
+
+    def score(*a):
+        return mapscore_call(*a, dims=dims, wrap=wrap, core_dims=core_dims,
+                             traffic=False, tile=512)
+
+    text = jax.jit(score).lower(*args).compile().as_text()
+    assert re.search(rf"%mapscore\.\d+ = \(f32\[{nb},8,128\]\S*, "
+                     rf"s32\[{nb},8,128\]\S*\) custom-call\(", text)
 
 
 @pytest.mark.parametrize("sfc", ["FZ", "H"])
