@@ -23,6 +23,15 @@ Candidate ``b`` of a batched sweep owns slot block
 segments that the sweep never activates, so bucketing changes no result
 bit.
 
+Everything per dimension is a struct of arrays: d separate slot
+vectors each for the extents, the flip bits and the candidates'
+priority rows, and the ranks at the current point order are the d rows
+of one dimension-major ``(d, N)`` gather; no array has the dimension as
+its minor axis.  Picking a slot's value at its cut dimension (or at a
+priority) is an unrolled select chain over the d vectors, elementwise
+work where a gather across a minor axis of size d would pad it to the
+TPU's 128 lanes.
+
 Bit-identity with the numpy engines (the ``np.lexsort`` tie order of
 ``partition._exact_order`` is the oracle) holds on a TPU too, where
 float64 is emulated with pairs of float32 and does not round like IEEE:
@@ -164,13 +173,22 @@ def _sweep(ranks, uvals, sdo, w, npl_tab, cut_tab, n, B, nparts, *, d,
               point's coordinate (equal values share a rank).
     uvals   : (d, npts_b) f64 — the distinct coordinate values by rank
               (read only for longest-dimension extents).
-    sdo     : (nb_b, d) i32 — per-candidate cut-dimension priority rows.
+    sdo     : (nb_b, d) i32 — per-candidate cut-dimension priority rows,
+              spread once into d loop-invariant ``(N,)`` vectors
+              ``sdo_v[p][slot] = sdo[block(slot), p]``.
     w       : (npts_b,) f64 — point weights (ignored unless weighted).
     npl_tab : (tab_b,) i32 — npl lookup by current part count.
     cut_tab : (cut_b, 3) i32 — unit-weight cut table (:func:`_cut_table`;
               ignored when weighted).
     n, B, nparts : traced scalars (real points / candidates / parts), so
         they stay OUT of the compile key; only the buckets are static.
+
+    The loop body holds no array with the dimension as its minor axis:
+    the ranks at the current point order are the rows of one ``(d, N)``
+    gather, the extents and the flip bits d ``(N,)`` vectors each, and
+    the cut dimension is picked from them by select chains (``pick``),
+    so the choice stays bit-for-bit the oracle's (the first strictly
+    longer extent in priority order).
 
     Returns (nb_b, npts_b) i32 part numbers in original point order.
     """
@@ -189,11 +207,21 @@ def _sweep(ranks, uvals, sdo, w, npl_tab, cut_tab, n, B, nparts, *, d,
     seg_np = jnp.where(real, nparts, 1).astype(_I32)
     mu = jnp.zeros(N, dtype=_I32)
     pts = local  # block-local id of the point at each position
-    flip = jnp.zeros((d, N), dtype=bool)       # per-segment coordinate signs
-    sdoN = jnp.repeat(sdo, npts_b, axis=0)     # (N, d), loop-invariant
-    dims_col = jnp.arange(d, dtype=_I32)[:, None]
+    # per-segment coordinate signs, one (N,) vector per dimension
+    flip = tuple(jnp.zeros(N, dtype=bool) for _ in range(d))
+    # loop-invariant priority rows, one (N,) vector per priority p:
+    # sdo_v[p][slot] = sdo[block(slot), p]
+    sdo_v = [jnp.repeat(sdo[:, p], npts_b) for p in range(d)]
     lpos = local.reshape(nb_b, npts_b)
     cut_size, cut_np, cut_k = cut_tab[:, 0], cut_tab[:, 1], cut_tab[:, 2]
+
+    def pick(cut, vals):
+        """``vals[cut]`` slot by slot, as a select chain over the d
+        per-dimension vectors (no gather across dimensions)."""
+        out = vals[-1]
+        for j in range(d - 2, -1, -1):
+            out = jnp.where(cut == j, vals[j], out)
+        return out
 
     def cond(state):
         _, _, _, _, seg_size, seg_np, _ = state
@@ -202,7 +230,9 @@ def _sweep(ranks, uvals, sdo, w, npl_tab, cut_tab, n, B, nparts, *, d,
     def body(state):
         level, pts, mu, seg_start, seg_size, seg_np, flip = state
         act = (seg_np > 1) & (seg_size > 1)
-        R = jnp.take(ranks, pts, axis=1)                        # (d, N)
+        # one gather of d-element rows, sliced: on a TPU d gathers of
+        # single ranks cost about d times as much
+        R = list(jnp.take(ranks, pts, axis=1))
 
         # --- cut dimension (reference: _pick_cut_dims / alternation) ----
         if d == 1:
@@ -218,25 +248,23 @@ def _sweep(ranks, uvals, sdo, w, npl_tab, cut_tab, n, B, nparts, *, d,
                 lo = jax.ops.segment_min(R[j], seg_start, num_segments=N,
                                          indices_are_sorted=True)
                 exts.append(uvals[j][hi[seg_start]] - uvals[j][lo[seg_start]])
-            ext = jnp.stack(exts, axis=1)                      # (N, d)
-            pri = jnp.take_along_axis(ext, sdoN, axis=1)
-            best_p = jnp.zeros(N, dtype=_I32)
-            best_e = pri[:, 0]
+            # the first strictly longer extent in priority order wins
+            cut = sdo_v[0]
+            best_e = pick(sdo_v[0], exts)
             for p in range(1, d):
-                better = pri[:, p] > best_e + 1e-12
-                best_p = jnp.where(better, p, best_p)
-                best_e = jnp.where(better, pri[:, p], best_e)
-            cut = jnp.take_along_axis(sdoN, best_p[:, None], axis=1)[:, 0]
+                pri = pick(sdo_v[p], exts)
+                better = pri > best_e + 1e-12
+                cut = jnp.where(better, sdo_v[p], cut)
+                best_e = jnp.where(better, pri, best_e)
         else:
-            cut = jnp.take(sdoN, level % d, axis=1).astype(_I32)
+            cut = pick(level % d, sdo_v)
 
         # --- segmented stable sort by the cut coordinate ----------------
         # the position is the last key, so the order is the stable one;
         # only three int32 operands ride the sort, the point ids follow
         # by one gather of the permutation
-        rc = jnp.take_along_axis(R, cut[None, :], axis=0)[0]
-        fc = jnp.take_along_axis(flip, cut[None, :], axis=0)[0]
-        ckey = jnp.where(fc, -rc, rc)
+        rc = pick(cut, R)
+        ckey = jnp.where(pick(cut, flip), -rc, rc)
         _, _, perm = lax.sort(
             ((seg_start - blk0).reshape(nb_b, npts_b),
              ckey.reshape(nb_b, npts_b), lpos),
@@ -278,12 +306,10 @@ def _sweep(ranks, uvals, sdo, w, npl_tab, cut_tab, n, B, nparts, *, d,
         ar = act & right
         mu = mu + jnp.where(ar, npl, 0).astype(_I32)
         if sfc == "Gray":
-            flip = flip ^ ar[None, :]
-        elif sfc == "FZ":
-            flip = flip ^ ((dims_col == cut[None, :]) & ar[None, :])
-        elif sfc == "FZlow":
-            flip = flip ^ ((dims_col == cut[None, :])
-                           & (act & ~right)[None, :])
+            flip = tuple(f ^ ar for f in flip)
+        elif sfc in ("FZ", "FZlow"):
+            side = ar if sfc == "FZ" else act & ~right
+            flip = tuple(f ^ ((cut == j) & side) for j, f in enumerate(flip))
         seg_np = jnp.where(act, jnp.where(right, seg_np - npl, npl),
                            seg_np).astype(_I32)
         seg_start = jnp.where(ar, seg_start + k, seg_start).astype(_I32)
