@@ -6,9 +6,10 @@ runs as a ``lax.while_loop`` over fixed-shape state, with
 
 - the per-level *segmented stable partition* expressed as one narrow
   ``lax.sort`` per candidate block over int32 ``(segment, coordinate
-  rank, position)`` keys, whose permutation then gathers the point ids,
+  key, position)`` keys, whose permutation then gathers the point ids,
 - segment extents (longest-dimension selection) via
-  ``jax.ops.segment_max/min`` of the ranks, keyed by segment start,
+  ``jax.ops.segment_max/min`` of the coordinate keys, keyed by segment
+  start,
 - cut placement from a host-built table of the unit-weight recursion
   (or a sequential per-segment weight prefix scan, ``lax.scan``), and
 - the SFC coordinate flips as per-segment sign bits.
@@ -36,18 +37,24 @@ Bit-identity with the numpy engines (the ``np.lexsort`` tie order of
 ``partition._exact_order`` is the oracle) holds on a TPU too, where
 float64 is emulated with pairs of float32 and does not round like IEEE:
 
-- coordinates enter as dense per-dimension ranks (``np.unique`` on the
-  host), so the sort keys are exact int32: equal values tie, a flip
-  negates the rank, and ``-0.0`` ties with ``0.0`` as in numpy;
+- coordinates enter as exact int32 sort keys, one row per dimension: a
+  *lattice* dimension (every value an integer, spanning less than
+  ``2**31``) is keyed by its value less its minimum, any other by its
+  dense rank (``np.unique`` on the host).  Either way equal values tie,
+  a flip negates the key, and ``-0.0`` ties with ``0.0`` as in numpy;
+- a lattice dimension's longest-dimension extent is the difference of
+  its keys, the oracle's IEEE ``max - min`` exactly, with no lookup
+  into the value table, and integer extents need no tolerance;
 - unit-weight cuts depend only on ``(segment size, part count)``, so the
   host runs the oracle's own ``_uniform_cuts`` arithmetic over the
   recursion once and ships the resulting cut table;
 - the Hilbert grid is quantised on the host by the oracle's quantiser.
 
-What stays in float64 on the device is the longest-dimension extent
-(compared with the oracle's ``1e-12`` tolerance, far above the error
-of the emulation) and the weighted cut arithmetic (a sequential
-``lax.scan``, exact on integer weights).
+What stays in float64 on the device is the extent of a ranked
+dimension (compared with the oracle's ``1e-12`` tolerance, far above
+the error of the emulation) and the weighted cut arithmetic (a
+sequential ``lax.scan``, exact on integer weights); on lattice input
+the unweighted loop body holds no float64 at all.
 
 Shape bucketing + the keyed compile cache mirror
 :mod:`repro.core.metrics_jax`: point counts pad to power-of-two buckets
@@ -166,13 +173,15 @@ def _cut_table(n: int, nparts: int, uneven_prime: bool) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 def _sweep(ranks, uvals, sdo, w, npl_tab, cut_tab, n, B, nparts, *, d,
-           sfc, longest_dim, weighted, npts_b, nb_b):
+           sfc, longest_dim, weighted, npts_b, nb_b, lat):
     """One whole batched partition on device.
 
-    ranks   : (d, npts_b) i32 — dense per-dimension rank of each padded
-              point's coordinate (equal values share a rank).
+    ranks   : (d, npts_b) i32 — each padded point's key per dimension:
+              its value less the minimum where ``lat[j]``, else the
+              dense rank of its value (equal values share a key).
     uvals   : (d, npts_b) f64 — the distinct coordinate values by rank
-              (read only for longest-dimension extents).
+              (read only for the longest-dimension extents of ranked
+              dimensions; ``(d, 1)`` when every dimension is lattice).
     sdo     : (nb_b, d) i32 — per-candidate cut-dimension priority rows,
               spread once into d loop-invariant ``(N,)`` vectors
               ``sdo_v[p][slot] = sdo[block(slot), p]``.
@@ -182,6 +191,7 @@ def _sweep(ranks, uvals, sdo, w, npl_tab, cut_tab, n, B, nparts, *, d,
               ignored when weighted).
     n, B, nparts : traced scalars (real points / candidates / parts), so
         they stay OUT of the compile key; only the buckets are static.
+    lat     : static per-dimension booleans, the lattice dimensions.
 
     The loop body holds no array with the dimension as its minor axis:
     the ranks at the current point order are the rows of one ``(d, N)``
@@ -239,21 +249,31 @@ def _sweep(ranks, uvals, sdo, w, npl_tab, cut_tab, n, B, nparts, *, d,
             cut = jnp.zeros(N, dtype=_I32)
         elif longest_dim:
             # a segment's flips are uniform, so its extent is that of
-            # the unflipped values: max - min by rank, exactly as IEEE
-            # subtracts the flipped max and min in the oracle
+            # the unflipped values, exactly as IEEE subtracts the
+            # flipped max and min in the oracle: a lattice key is the
+            # value less a constant, so its max - min is the extent
+            # itself; a ranked dimension looks both ends up by rank
+            exact = all(lat)  # integer extents: int32, no tolerance
             exts = []
             for j in range(d):
                 hi = jax.ops.segment_max(R[j], seg_start, num_segments=N,
                                          indices_are_sorted=True)
                 lo = jax.ops.segment_min(R[j], seg_start, num_segments=N,
                                          indices_are_sorted=True)
-                exts.append(uvals[j][hi[seg_start]] - uvals[j][lo[seg_start]])
+                if lat[j]:
+                    # an empty segment's entry wraps here, and is never
+                    # read: every slot reads its own, non-empty segment
+                    e = (hi - lo)[seg_start]
+                    exts.append(e if exact else e.astype(_F64))
+                else:
+                    exts.append(uvals[j][hi[seg_start]]
+                                - uvals[j][lo[seg_start]])
             # the first strictly longer extent in priority order wins
             cut = sdo_v[0]
             best_e = pick(sdo_v[0], exts)
             for p in range(1, d):
                 pri = pick(sdo_v[p], exts)
-                better = pri > best_e + 1e-12
+                better = pri > (best_e if exact else best_e + 1e-12)
                 cut = jnp.where(better, sdo_v[p], cut)
                 best_e = jnp.where(better, pri, best_e)
         else:
@@ -426,19 +446,20 @@ def _hilbert_sweep(grid, uvals, sdo, w, npl_tab, cut_tab, n, B, nparts,
 
 @functools.lru_cache(maxsize=None)
 def _engine(d, sfc, longest_dim, weighted, npts_b, nb_b, tab_b, cut_b,
-            bits):
+            bits, lat):
     """One jit-compiled sweep per (engine knobs, shape bucket).
 
     ``tab_b`` and ``cut_b`` are part of the key even though the function
     never reads them: every cache entry then sees exactly ONE input
     shape set, so the ``lru_cache`` hit/miss counters are a truthful
     compile-count proxy (mirrors ``metrics_jax._scorer``).  ``bits`` is
-    the static Hilbert resolution (0 for the MJ sweeps); call sites
-    MUST pass every key positionally — ``lru_cache`` keys keyword
-    spellings separately and a split key would double-compile.  For
-    ``sfc == "H"`` callers canonicalise ``longest_dim=True`` (Hilbert
-    has no cut dimensions) so the knob cannot fragment the cache
-    either.
+    the static Hilbert resolution (0 for the MJ sweeps) and ``lat``
+    the MJ sweeps' lattice dimensions (``()`` for Hilbert), both read
+    from the input by :func:`_prepare`; call sites MUST pass every key
+    positionally — ``lru_cache`` keys keyword spellings separately and a
+    split key would double-compile.  For ``sfc == "H"`` callers
+    canonicalise ``longest_dim=True`` (Hilbert has no cut dimensions)
+    so the knob cannot fragment the cache either.
 
     The jitted functions are named, so the device trace shows the
     engine as the modules ``jit_partition_mj`` and
@@ -454,7 +475,7 @@ def _engine(d, sfc, longest_dim, weighted, npts_b, nb_b, tab_b, cut_b,
 
     def partition_mj(*args):
         return _sweep(*args, d=d, sfc=sfc, longest_dim=longest_dim,
-                      weighted=weighted, npts_b=npts_b, nb_b=nb_b)
+                      weighted=weighted, npts_b=npts_b, nb_b=nb_b, lat=lat)
     return jax.jit(partition_mj)
 
 
@@ -468,28 +489,47 @@ partition_cache_stats, reset_partition_cache = \
 # host entry points (the orderings-module backend contract)
 # ---------------------------------------------------------------------------
 
+def _is_lattice(col: np.ndarray) -> bool:
+    """Every value an integer, ``max - min`` below ``2**31``: the column
+    is then keyed by ``value - min`` in int32, exactly (float64 holds
+    every such difference)."""
+    lo, hi = col.min(), col.max()
+    return bool(np.isfinite(lo) and np.isfinite(hi) and hi - lo < 2.0**31
+                and (col == np.floor(col)).all())
+
+
 def _prepare(coords, nparts, sfc, dim_orders, weights, uneven_prime):
     """Padded device inputs of one batched call and the shape keys of
     :func:`_engine`: returns ``(args, (npts_b, nb_b, tab_b, cut_b,
-    bits))``, ``args`` being the engine's first six positional inputs
-    (per-dim ranks or the Hilbert grid, distinct values, dim orders,
-    weights, split table, cut table)."""
+    bits, lat))``, ``args`` being the engine's first six positional
+    inputs (per-dim keys or the Hilbert grid, distinct values, dim
+    orders, weights, split table, cut table).  ``lat`` marks the MJ
+    sweep's lattice dimensions (:func:`_is_lattice`); it is read from
+    the values, so one deployment's requests share it, and its count
+    goes to the counter ``partition.lattice_dims``."""
     n, d = coords.shape
     B = len(dim_orders)
     npts_b = bucket_size(n, PART_BUCKET_MIN)
     nb_b = bucket_size(B, lo=1)
     if sfc == "H":
-        bits = hilbert_bits(n, d)
+        bits, lat = hilbert_bits(n, d), ()
         keys = _hilbert_quantise(coords, bits).T.astype(np.int32)
         uvals = np.zeros((d, 1), dtype=np.float64)
     else:
         bits = 0
+        lat = tuple(_is_lattice(coords[:, j]) for j in range(d))
         keys = np.empty((d, n), dtype=np.int32)
-        uvals = np.zeros((d, npts_b), dtype=np.float64)
+        # the value table is read only for ranked dimensions
+        uvals = np.zeros((d, 1 if all(lat) else npts_b), dtype=np.float64)
         for j in range(d):
-            u, inv = np.unique(coords[:, j], return_inverse=True)
-            keys[j] = inv.reshape(-1)
-            uvals[j, :len(u)] = u
+            col = coords[:, j]
+            if lat[j]:
+                keys[j] = col - col.min()
+            else:
+                u, inv = np.unique(col, return_inverse=True)
+                keys[j] = inv.reshape(-1)
+                uvals[j, :len(u)] = u
+    obs.counter("partition.lattice_dims", sum(lat))
     keys = pad_axis(keys, npts_b, axis=1)
     sdo = np.tile(np.arange(d, dtype=np.int32), (nb_b, 1))
     sdo[:B] = dim_orders
@@ -505,7 +545,7 @@ def _prepare(coords, nparts, sfc, dim_orders, weights, uneven_prime):
     tab_b = bucket_size(len(tab), lo=TAB_MIN)
     tab = pad_axis(tab, tab_b)
     return (keys, uvals, sdo, w, tab, cut), (npts_b, nb_b, tab_b, cut_b,
-                                             bits)
+                                             bits, lat)
 
 
 def order_points_batched_jax(
@@ -536,14 +576,15 @@ def order_points_batched_jax(
         return vectorized_order_batched(  # pragma: no cover
             coords, nparts, sfc, dim_orders=dim_orders, weights=weights,
             longest_dim=longest_dim, uneven_prime=uneven_prime)
-    args, (npts_b, nb_b, tab_b, cut_b, bits) = _prepare(
+    args, (npts_b, nb_b, tab_b, cut_b, bits, lat) = _prepare(
         coords, nparts, sfc, dim_orders, weights, uneven_prime)
     ld = True if sfc == "H" else bool(longest_dim)  # canonical H cache key
     misses0 = _engine.cache_info().misses
     fn = _engine(d, sfc, ld, weights is not None,
-                 npts_b, nb_b, tab_b, cut_b, bits)
+                 npts_b, nb_b, tab_b, cut_b, bits, lat)
     obs.annotate(compile_cache=(
-        "miss" if _engine.cache_info().misses > misses0 else "hit"))
+        "miss" if _engine.cache_info().misses > misses0 else "hit"),
+        lattice_dims=sum(lat))
     out = fn(*args, np.int32(n), np.int32(B), np.int32(nparts))
     return np.asarray(out)[:B, :n].astype(np.int64)
 

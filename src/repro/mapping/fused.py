@@ -65,9 +65,9 @@ MAX_FUSED_ELEMS = 1 << 27
 
 def _build(*, d_t, task_sfc, d_p, proc_sfc, longest_dim, weighted,
            tnum, pnum, t_sel, p_sel, npts_bt, nbt_b, npts_bp, nbp_b,
-           tab_b, cut_bt, cut_bp, bits_t, bits_p, dims, wrap, core_dims,
-           objective, traffic, score_kind, ne, ne_b, nb_b, ncols, tile,
-           interpret, refine):
+           tab_b, cut_bt, cut_bp, bits_t, bits_p, lat_t, lat_p, dims,
+           wrap, core_dims, objective, traffic, score_kind, ne, ne_b, nb_b,
+           ncols, tile, interpret, refine):
     """The traced body of one fused program (all kwargs static).
 
     ``refine`` is ``None`` (sweep only) or a static ``(rounds, top,
@@ -82,9 +82,9 @@ def _build(*, d_t, task_sfc, d_p, proc_sfc, longest_dim, weighted,
     ld_t = True if task_sfc == "H" else longest_dim
     ld_p = True if proc_sfc == "H" else longest_dim
     eng_t = _pj._engine(d_t, task_sfc, ld_t, weighted,
-                        npts_bt, nbt_b, tab_b, cut_bt, bits_t)
+                        npts_bt, nbt_b, tab_b, cut_bt, bits_t, lat_t)
     eng_p = _pj._engine(d_p, proc_sfc, ld_p, False,
-                        npts_bp, nbp_b, tab_b, cut_bp, bits_p)
+                        npts_bp, nbp_b, tab_b, cut_bp, bits_p, lat_p)
     if score_kind == "jax":
         score_fn = metrics_jax._scorer(dims, wrap, core_dims, traffic,
                                        ne_b, nb_b)
@@ -409,9 +409,9 @@ def _build(*, d_t, task_sfc, d_p, proc_sfc, longest_dim, weighted,
 @functools.lru_cache(maxsize=None)
 def _program(d_t, task_sfc, d_p, proc_sfc, longest_dim, weighted,
              tnum, pnum, t_sel, p_sel, npts_bt, nbt_b, npts_bp, nbp_b,
-             tab_b, cut_bt, cut_bp, bits_t, bits_p, dims, wrap, core_dims,
-             objective, traffic, score_kind, ne, ne_b, nb_b, ncols, tile,
-             interpret, refine):
+             tab_b, cut_bt, cut_bp, bits_t, bits_p, lat_t, lat_p, dims,
+             wrap, core_dims, objective, traffic, score_kind, ne, ne_b, nb_b,
+             ncols, tile, interpret, refine):
     """One jitted fused program per (pipeline knobs, shape bucket).
 
     Every cache entry sees exactly one input shape set, so the
@@ -423,10 +423,11 @@ def _program(d_t, task_sfc, d_p, proc_sfc, longest_dim, weighted,
         longest_dim=longest_dim, weighted=weighted, tnum=tnum, pnum=pnum,
         t_sel=t_sel, p_sel=p_sel, npts_bt=npts_bt, nbt_b=nbt_b,
         npts_bp=npts_bp, nbp_b=nbp_b, tab_b=tab_b, cut_bt=cut_bt,
-        cut_bp=cut_bp, bits_t=bits_t, bits_p=bits_p, dims=dims, wrap=wrap,
-        core_dims=core_dims, objective=objective, traffic=traffic,
-        score_kind=score_kind, ne=ne, ne_b=ne_b, nb_b=nb_b, ncols=ncols,
-        tile=tile, interpret=interpret, refine=refine))
+        cut_bp=cut_bp, bits_t=bits_t, bits_p=bits_p, lat_t=lat_t,
+        lat_p=lat_p, dims=dims, wrap=wrap, core_dims=core_dims,
+        objective=objective, traffic=traffic, score_kind=score_kind, ne=ne,
+        ne_b=ne_b, nb_b=nb_b, ncols=ncols, tile=tile, interpret=interpret,
+        refine=refine))
 
 
 # registry-backed stat/reset pair (repro.obs); auto-registers with
@@ -502,10 +503,10 @@ class FusedSweep:
         p_sel = tuple(p_of[p] for p in p_perms)
         task_sfc, proc_sfc = pipe._sfc_pair(td, pd)
 
-        args_t, (npts_bt, nbt_b, tab_b, cut_bt, bits_t) = _pj._prepare(
-            tc, pnum, task_sfc, np.array(ut, dtype=np.int64), task_weights,
-            cfg.uneven_prime)
-        args_p, (npts_bp, nbp_b, _, cut_bp, bits_p) = _pj._prepare(
+        args_t, (npts_bt, nbt_b, tab_b, cut_bt, bits_t, lat_t) = \
+            _pj._prepare(tc, pnum, task_sfc, np.array(ut, dtype=np.int64),
+                         task_weights, cfg.uneven_prime)
+        args_p, (npts_bp, nbp_b, _, cut_bp, bits_p, lat_p) = _pj._prepare(
             pc, pnum, proc_sfc, np.array(up, dtype=np.int64), None,
             cfg.uneven_prime)
 
@@ -538,7 +539,7 @@ class FusedSweep:
         fn = _program(td, task_sfc, pd, proc_sfc, bool(cfg.longest_dim),
                       task_weights is not None, tnum, pnum, t_sel, p_sel,
                       npts_bt, nbt_b, npts_bp, nbp_b, tab_b, cut_bt,
-                      cut_bp, bits_t, bits_p,
+                      cut_bp, bits_t, bits_p, lat_t, lat_p,
                       tuple(int(x) for x in machine.dims),
                       tuple(bool(x) for x in machine.wrap),
                       machine.core_dims, tuple(objective), traffic, kind,
@@ -552,7 +553,8 @@ class FusedSweep:
         # from the call of the program to the last read of its outputs:
         # upload of the partition inputs, the device run, read-back
         with obs.span("fused.execute", candidates=ncand,
-                      points=int(tnum + pnum)):
+                      points=int(tnum + pnum), lattice_dims_t=sum(lat_t),
+                      lattice_dims_p=sum(lat_p)):
             out = fn(args_t, args_p, edges, ew, ew64, acoords, bw)
             best_i, t2p, scores, ok = out[:4]
             if not bool(ok):

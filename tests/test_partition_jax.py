@@ -166,24 +166,19 @@ def test_cut_table_matches_oracle_recursion(n, nparts, uneven):
     assert set(rows) == seen
 
 
-@pytest.mark.parametrize("d", [3, 5])
-@pytest.mark.parametrize("longest", [True, False],
-                         ids=["longest", "alternate"])
-def test_cut_choice_has_no_cross_dimension_gathers(d, longest):
-    """The MJ loop body keeps the cut choice in one (N,) vector per
-    dimension: no gather or concatenate in it (or in a function it
-    calls) reads or builds an array whose minor axis is the dimension,
-    which a TPU pads to 128 lanes and gathers across."""
+def _mj_loop_body(coords, longest=True, weights=None):
+    """Lower the MJ engine for ``coords`` (two candidates, 8 parts) and
+    return the module's text, the text of its while loop's body and of
+    every function the body calls, and the slot count N."""
     import re
 
-    rng = np.random.default_rng(9)
-    coords = rng.random((300, d))
+    n, d = coords.shape
     dos = np.stack([np.arange(d), np.arange(d)[::-1]])
-    args, (npts_b, nb_b, tab_b, cut_b, bits) = partition_jax._prepare(
-        coords, 8, "FZ", dos, None, False)
-    engine = partition_jax._engine(d, "FZ", longest, False, npts_b, nb_b,
-                                   tab_b, cut_b, bits)
-    text = engine.lower(*args, np.int32(300), np.int32(2),
+    args, (npts_b, nb_b, tab_b, cut_b, bits, lat) = partition_jax._prepare(
+        coords, 8, "FZ", dos, weights, False)
+    engine = partition_jax._engine(d, "FZ", longest, weights is not None,
+                                   npts_b, nb_b, tab_b, cut_b, bits, lat)
+    text = engine.lower(*args, np.int32(n), np.int32(2),
                         np.int32(8)).as_text()
     funcs = dict(re.findall(r"func\.func \w+ @(\w+)\((.*?)\n  }\n",
                             text, re.S))
@@ -202,13 +197,229 @@ def test_cut_choice_has_no_cross_dimension_gathers(d, longest):
             seen.add(name)
             body.append(funcs[name])
             todo += re.findall(r"call @(\w+)\(", funcs[name])
-    ops = [ln for ln in "\n".join(body).splitlines()
+    return text, "\n".join(body), nb_b * npts_b
+
+
+@pytest.mark.parametrize("d", [3, 5])
+@pytest.mark.parametrize("longest", [True, False],
+                         ids=["longest", "alternate"])
+def test_cut_choice_has_no_cross_dimension_gathers(d, longest):
+    """The MJ loop body keeps the cut choice in one (N,) vector per
+    dimension: no gather or concatenate in it (or in a function it
+    calls) reads or builds an array whose minor axis is the dimension,
+    which a TPU pads to 128 lanes and gathers across."""
+    import re
+
+    coords = np.random.default_rng(9).random((300, d))
+    _, body, _ = _mj_loop_body(coords, longest)
+    ops = [ln for ln in body.splitlines()
            if re.search(r"stablehlo\.(gather|concatenate)\b", ln)]
     assert any("stablehlo.gather" in ln for ln in ops)  # the probe sees
     for ln in ops:
         for shape in re.findall(r"tensor<((?:\d+x)+)\w+>", ln):
             dims = [int(x) for x in shape.rstrip("x").split("x")]
             assert not (len(dims) > 1 and dims[-1] == d), ln
+
+
+@pytest.mark.parametrize("d", [3, 5])
+@pytest.mark.parametrize("kind", ["lattice", "lattice-weighted", "float"])
+def test_lattice_extents_read_no_value_table(d, kind):
+    """On integer coordinates the extents are key differences: the MJ
+    loop body (with the functions it calls) has exactly one gather per
+    dimension for them, holds no float64 unless weighted, and nothing
+    in the program reads the value table (jit drops an unread input).
+    On float coordinates each dimension still looks both ends up in its
+    float64 value table: two int32 and two float64 gathers."""
+    import re
+
+    rng = np.random.default_rng(9)
+    n = 300
+    if kind == "float":
+        coords = rng.random((n, d))
+    else:  # a sparse allocation's router coordinates, with gaps
+        coords = (rng.integers(0, 24, size=(n, d)) * 3 - 7).astype(float)
+    weights = rng.random(n) if kind == "lattice-weighted" else None
+    text, body, N = _mj_loop_body(coords, weights=weights)
+    gathers = [ln for ln in body.splitlines()
+               if re.search(r"stablehlo\.gather\b", ln)]
+    # an extent gather broadcasts a segment-indexed array to the slots
+    ext = [ln for ln in gathers
+           if f": (tensor<{N}xi32>, tensor<{N}x1xi32>) -> tensor<{N}xi32>"
+           in ln]
+    f64 = [ln for ln in gathers if re.search(r"\(tensor<\d+xf64>", ln)]
+    main = re.search(r"func\.func public @main\((.*?)\)", text).group(1)
+    if kind == "float":
+        assert len(ext) == 2 * d and len(f64) == 2 * d
+        assert f"tensor<{d}x512xf64>" in main
+    else:
+        # the weighted cut broadcasts its segment sums once more
+        assert len(ext) == d + (weights is not None)
+        assert f"tensor<{d}x1xf64>" not in main  # the value table
+        if weights is None:
+            assert not f64 and "f64" not in body
+
+
+def _pair_batched(coords, nparts, sfc, dim_orders, weights=None,
+                  uneven_prime=False):
+    """The oracle's and the device engine's batched parts, and the
+    lattice dimensions the engine counted."""
+    from repro import obs
+
+    kw = dict(dim_orders=dim_orders, weights=weights,
+              uneven_prime=uneven_prime)
+    ref = order_points_batched(coords, nparts, sfc, backend="vectorized",
+                               **kw)
+    c0 = obs.snapshot()["counters"].get("partition.lattice_dims", 0)
+    out = order_points_batched(coords, nparts, sfc, backend="jax", **kw)
+    c1 = obs.snapshot()["counters"].get("partition.lattice_dims", 0)
+    return ref, out, c1 - c0
+
+
+def _rotations(rng, d, B):
+    return np.stack([rng.permutation(d) for _ in range(B)])
+
+
+def _lat_gaps(rng):
+    # a sparse allocation's router coordinates: distinct points of a
+    # 12 x 10 x 8 torus with the rest taken by other jobs
+    grid = np.indices((12, 10, 8)).reshape(3, -1).T.astype(float)
+    coords = grid[rng.choice(len(grid), size=300, replace=False)]
+    return _pair_batched(coords, 37, "FZ", _rotations(rng, 3, 4)) + (3,)
+
+
+def _lat_signed_zeros(rng):
+    coords = rng.integers(-3, 4, size=(150, 2)).astype(float)
+    coords[rng.random(coords.shape) < 0.3] = -0.0
+    coords[rng.random(coords.shape) < 0.2] = 0.0
+    runs = [_pair_batched(coords, 12, sfc, _rotations(rng, 2, 2))
+            for sfc in SFCS]
+    return (np.stack([r[0] for r in runs]), np.stack([r[1] for r in runs]),
+            sum(r[2] for r in runs), 2 * len(SFCS))
+
+
+def _lat_tied_extents(rng):
+    # equal extents in several dimensions, every priority row rotated
+    coords = _tied_lattice(rng, 5) - 2.0
+    dos = np.stack([np.roll(np.arange(5), r) for r in range(5)]
+                   + [np.roll(np.arange(5)[::-1], r) for r in range(3)])
+    return _pair_batched(coords, 29, "Gray", dos) + (5,)
+
+
+def _lat_mixed(rng):
+    # an integer, a half-integer (extents tie with the integer ones)
+    # and an integer dimension with gaps
+    coords = np.stack([rng.integers(0, 7, 250),
+                       rng.integers(0, 13, 250) / 2,
+                       rng.integers(-3, 4, 250) * 2], axis=1).astype(float)
+    return _pair_batched(coords, 23, "FZlow", _rotations(rng, 3, 3)) + (2,)
+
+
+def _lat_span_2_31(rng):
+    # a span of 2**31 falls back to ranks; 2**31 - 1 stays a lattice
+    coords = rng.integers(0, 5, size=(200, 3)).astype(float)
+    coords[:, 0] = rng.choice([0.0, 7.0, 2.0**31], size=200)
+    coords[:, 1] = rng.choice([-2.0**30, 5.0, 2.0**30 - 1], size=200)
+    coords[:3, 0] = [0.0, 7.0, 2.0**31]
+    coords[:3, 1] = [-2.0**30, 5.0, 2.0**30 - 1]
+    return _pair_batched(coords, 16, "FZ", _rotations(rng, 3, 3)) + (2,)
+
+
+def _lat_batched(rng):
+    # a BG/Q block torus after the shift, 16 rotations in one sweep
+    coords = np.indices((4, 4, 4, 4, 2)).reshape(5, -1).T.astype(float)
+    coords = coords[rng.permutation(len(coords))[:400]]
+    return _pair_batched(coords, 50, "FZ", _rotations(rng, 5, 16)) + (5,)
+
+
+def _lat_weighted(rng):
+    coords = (rng.integers(0, 9, size=(220, 3)) * 2).astype(float)
+    return _pair_batched(coords, 21, "FZ", _rotations(rng, 3, 2),
+                         weights=rng.random(220), uneven_prime=True) + (3,)
+
+
+def _lat_fused(rng):
+    # a MiniGhost-like grid on a sparse allocation of a small torus,
+    # through the one-program sweep, against the all-numpy pipeline
+    from repro import obs
+    from repro.core import make_machine, random_allocation, stencil_graph
+    from repro.mapping.pipeline import MappingPipeline, PipelineConfig
+
+    graph = stencil_graph((8, 4, 4))
+    alloc = random_allocation(make_machine((8, 8, 6)), graph.n, seed=4)
+    base = MappingPipeline(PipelineConfig(rotations=4)).map(graph, alloc)
+    pipe = MappingPipeline(PipelineConfig(
+        rotations=4, score_backend="jax", partition_backend="jax"))
+    c0 = obs.snapshot()["counters"].get("partition.lattice_dims", 0)
+    fused = pipe.map(graph, alloc)
+    c1 = obs.snapshot()["counters"].get("partition.lattice_dims", 0)
+    assert fused.stats.get("fused") is True
+    assert base.rotation == fused.rotation
+    return base.task_to_proc, fused.task_to_proc, c1 - c0, 6
+
+
+LATTICE_CASES = {
+    "gaps": _lat_gaps, "signed-zeros": _lat_signed_zeros,
+    "tied-extents": _lat_tied_extents, "mixed": _lat_mixed,
+    "span-2-31": _lat_span_2_31, "batched": _lat_batched,
+    "weighted": _lat_weighted, "fused": _lat_fused,
+}
+
+
+@pytest.mark.parametrize("case", sorted(LATTICE_CASES))
+def test_lattice_keys_bit_identity(case):
+    """Integer-valued dimensions are keyed by value, and their extents
+    are key differences: the parts stay the numpy oracle's, bit for bit,
+    and the engine counts the dimensions that took that path."""
+    ref, out, counted, want = LATTICE_CASES[case](
+        np.random.default_rng(1700 + sorted(LATTICE_CASES).index(case)))
+    assert np.array_equal(ref, out), (ref != out).sum()
+    assert counted == want
+
+
+def test_lattice_flags_compile_once():
+    """The lattice flags are read from the values, so allocations that
+    differ only in their gaps share one engine and one fused program,
+    and float clouds another; ``partition.lattice_dims`` counts d for
+    each lattice side."""
+    from repro import obs
+    from repro.core import (make_machine, random_allocation, stencil_graph,
+                            TaskGraph)
+    from repro.mapping import fused as fused_mod
+    from repro.mapping.pipeline import MappingPipeline, PipelineConfig
+
+    def counted(fn):
+        c0 = obs.snapshot()["counters"].get("partition.lattice_dims", 0)
+        fn()
+        return obs.snapshot()["counters"].get(
+            "partition.lattice_dims", 0) - c0
+
+    rng = np.random.default_rng(23)
+    machine = make_machine((8, 8, 6))
+    dos = _rotations(rng, 3, 2)
+    partition_jax.reset_partition_cache()
+    for misses, cloud, dims in (
+            (1, random_allocation(machine, 200, seed=1).coords, 3),
+            (1, random_allocation(machine, 200, seed=2).coords, 3),
+            (2, rng.random((200, 3)), 0), (2, rng.random((200, 3)), 0)):
+        assert counted(lambda: partition_jax.order_points_batched_jax(
+            cloud.astype(float), 16, "FZ", dim_orders=dos)) == dims
+        assert partition_jax.partition_cache_stats()["misses"] == misses
+
+    graph = stencil_graph((8, 4, 4))
+    pipe = MappingPipeline(PipelineConfig(
+        rotations=4, score_backend="jax", partition_backend="jax"))
+    fused_mod.reset_fused_cache()
+    for misses, g, seed, dims in (
+            (1, graph, 1, 6), (1, graph, 2, 6),
+            (2, TaskGraph(graph.coords + rng.random(graph.coords.shape),
+                          graph.edges, graph.weights), 1, 3),
+            (2, TaskGraph(graph.coords + rng.random(graph.coords.shape),
+                          graph.edges, graph.weights), 2, 3)):
+        alloc = random_allocation(machine, graph.n, seed=seed)
+        res = []
+        assert counted(lambda: res.append(pipe.map(g, alloc))) == dims
+        assert res[0].stats.get("fused") is True
+        assert fused_mod.fused_cache_stats()["misses"] == misses
 
 
 # ---------------------------------------------------------------------------
@@ -481,10 +692,10 @@ def test_engine_programs_are_named(sfc, module):
     """The engine's own programs carry stable names, so a device trace
     shows them as modules ``jit_partition_*`` (not ``jit__unknown``)."""
     coords = np.random.default_rng(3).random((300, 3))
-    args, (npts_b, nb_b, tab_b, cut_b, bits) = partition_jax._prepare(
+    args, (npts_b, nb_b, tab_b, cut_b, bits, lat) = partition_jax._prepare(
         coords, 8, sfc, np.array([[0, 1, 2]]), None, False)
     engine = partition_jax._engine(3, sfc, True, False, npts_b, nb_b,
-                                   tab_b, cut_b, bits)
+                                   tab_b, cut_b, bits, lat)
     text = engine.lower(*args, np.int32(300), np.int32(1),
                         np.int32(8)).as_text()
     assert f"module @{module} " in text

@@ -113,14 +113,39 @@ def test_partition_engine_compiles_for_v5e(one_chip, sfc):
     n, d = ENGINE_POINTS, 3
     coords = np.random.default_rng(0).random((n, d))
     dim_orders = np.stack([np.arange(d), np.arange(d)[::-1]])
-    args, (npts_b, nb_b, tab_b, cut_b, bits) = partition_jax._prepare(
+    args, (npts_b, nb_b, tab_b, cut_b, bits, lat) = partition_jax._prepare(
         coords, n, sfc, dim_orders[:ENGINE_CANDIDATES], None, False)
     assert (npts_b, nb_b) == (ENGINE_POINTS, ENGINE_CANDIDATES)
     specs = [_spec(np.shape(a), np.asarray(a).dtype, one_chip)
              for a in args]
     specs += [_spec((), np.int32, one_chip)] * 3
     engine = partition_jax._engine(d, sfc, True, False, npts_b, nb_b,
-                                   tab_b, cut_b, bits)
+                                   tab_b, cut_b, bits, lat)
     compiled = engine.lower(*specs).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < \
         TEMP_SHARE * HBM_BYTES
+
+
+@pytest.mark.parametrize("weighted", [False, True],
+                         ids=["unweighted", "weighted"])
+def test_lattice_engine_compiles_for_v5e(one_chip, weighted):
+    """The MJ engine on integer coordinates (int32 extents, no value
+    table) compiles for the chip, and unweighted it holds no float64."""
+    n, d = ENGINE_POINTS, 3
+    rng = np.random.default_rng(0)
+    coords = (rng.integers(0, 25, size=(n, d)) * 2).astype(float)
+    weights = rng.random(n) if weighted else None
+    dim_orders = np.stack([np.arange(d), np.arange(d)[::-1]])
+    args, (npts_b, nb_b, tab_b, cut_b, bits, lat) = partition_jax._prepare(
+        coords, n, "FZ", dim_orders[:ENGINE_CANDIDATES], weights, False)
+    assert lat == (True,) * d
+    specs = [_spec(np.shape(a), np.asarray(a).dtype, one_chip)
+             for a in args]
+    specs += [_spec((), np.int32, one_chip)] * 3
+    engine = partition_jax._engine(d, "FZ", True, weighted, npts_b, nb_b,
+                                   tab_b, cut_b, bits, lat)
+    compiled = engine.lower(*specs).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        TEMP_SHARE * HBM_BYTES
+    if not weighted:
+        assert "f64" not in compiled.as_text()
