@@ -39,9 +39,8 @@ import time
 
 import numpy as np
 
-from repro.core import (Mapper, MapperConfig, evaluate, gemini_xk7,
-                        sfc_allocation, stencil_graph)
-from repro.hier import HierarchySpec
+from repro.core import evaluate, gemini_xk7, sfc_allocation, stencil_graph
+from repro.mapping import HierarchySpec, MappingPipeline, PipelineConfig
 
 ROTATIONS = 8  # the MiniGhost benchmark's §4.3 search budget
 
@@ -100,13 +99,13 @@ def run(n: int = 1 << 18, cores_per_node: int = 16, *,
         depths: tuple = DEPTHS) -> dict:
     machine = _machine(n, cores_per_node)
     graph = stencil_graph(_grid(n), torus=False)
-    flat = Mapper(MapperConfig(sfc="FZ", shift=True, rotations=rotations))
-    node = Mapper(MapperConfig(sfc="FZ", shift=True, rotations=rotations,
-                               hierarchy=HierarchySpec.node()))
-    deep = {d: Mapper(MapperConfig(sfc="FZ", shift=True,
-                                   rotations=rotations,
-                                   hierarchy=HierarchySpec.with_depth(d)))
-            for d in depths}
+    def _pipe(hierarchy):
+        return MappingPipeline(PipelineConfig(
+            sfc="FZ", shift=True, rotations=rotations, hierarchy=hierarchy))
+
+    flat = _pipe(HierarchySpec.flat())
+    node = _pipe(HierarchySpec.node())
+    deep = {d: _pipe(HierarchySpec.with_depth(d)) for d in depths}
 
     out: dict = {"n": n, "cores_per_node": cores_per_node,
                  "scenarios": {}}

@@ -13,8 +13,8 @@ transforms (Fig. 7) and optionally the "+E" architecture optimisation
 (drop the E dim from node coords so E-neighbour pairs stay together).
 
 Wall-clock is not measurable here; we report the paper's §3 metrics.
-Z2 variants run through the unified ``repro.mapping`` pipeline via
-``repro.core.Mapper``.
+Z2 variants run through the unified ``repro.mapping`` pipeline
+(``MappingPipeline``).
 Findings to match: per-dim Data — SFC overloads D/E links and starves
 A/B/C; Z2 balances them and cuts max Data; improvements grow with rank
 count (8K -> 32K).
@@ -26,10 +26,10 @@ import time
 
 import numpy as np
 
-from repro.core import (Mapper, MapperConfig, MappingResult, bgq,
-                        block_allocation, cube_coords, cube_sphere_graph,
-                        evaluate, face2d_coords)
+from repro.core import (MappingResult, bgq, block_allocation, cube_coords,
+                        cube_sphere_graph, evaluate, face2d_coords)
 from repro.core.orderings import hilbert_index
+from repro.mapping import MappingPipeline, PipelineConfig
 
 
 # allocation shapes used on Mira for 512/1024/2048 nodes (x16 ranks/node)
@@ -90,8 +90,8 @@ def run_point(nranks: int, *, transforms=("sphere", "cube", "face2d"),
         for pe in plus_e:
             drop = (4,) if pe else ()   # E is dim index 4 of (A,B,C,D,E)
             tag = f"Z2-{tname}" + ("+E" if pe else "")
-            mapper = Mapper(MapperConfig(sfc="FZ", shift=True, drop=drop,
-                                         rotations=ROTATIONS))
+            mapper = MappingPipeline(PipelineConfig(
+                sfc="FZ", shift=True, drop=drop, rotations=ROTATIONS))
             res = mapper.map(graph, alloc, task_coords=tc)
             out[tag] = evaluate(graph, alloc, res)
 

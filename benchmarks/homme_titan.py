@@ -14,7 +14,7 @@ matches SFC; Z2_3 cuts Latency(M) (up to ~18% at 86,400 ranks in the
 paper) while RAISING WeightedHops ~25% — the bandwidth-aware trade.
 Per-dim: SFC's worst latency sits on the slow Y cables; Z2_3 moves
 traffic to fast X/Z links.  Z2 variants run through the unified
-``repro.mapping`` pipeline via ``repro.core.Mapper``.
+``repro.mapping`` pipeline (``MappingPipeline``).
 """
 
 from __future__ import annotations
@@ -23,10 +23,10 @@ import time
 
 import numpy as np
 
-from repro.core import (Mapper, MapperConfig, MappingResult, cube_coords,
-                        cube_sphere_graph, evaluate, gemini_xk7,
-                        sfc_allocation)
+from repro.core import (MappingResult, cube_coords, cube_sphere_graph,
+                        evaluate, gemini_xk7, sfc_allocation)
 from repro.core.orderings import hilbert_index
+from repro.mapping import MappingPipeline, PipelineConfig
 
 NE = 120
 CORES_PER_ROUTER = 32
@@ -79,15 +79,15 @@ def run_point(nranks: int, seed: int) -> dict:
     out["SFC"] = evaluate(graph, alloc, MappingResult(parts))
     out["SFC-ideal"] = evaluate(graph, alloc_raw, MappingResult(parts))
     variants = {
-        "Z2_1": MapperConfig(sfc="FZ", shift=True, rotations=ROTATIONS),
-        "Z2_2": MapperConfig(sfc="FZ", shift=True, uneven_prime=True,
-                             bandwidth_scale=True, rotations=ROTATIONS),
-        "Z2_3": MapperConfig(sfc="FZ", shift=True, uneven_prime=True,
-                             bandwidth_scale=True, box=(2, 2, 8),
-                             rotations=ROTATIONS),
+        "Z2_1": PipelineConfig(sfc="FZ", shift=True, rotations=ROTATIONS),
+        "Z2_2": PipelineConfig(sfc="FZ", shift=True, uneven_prime=True,
+                               bandwidth_scale=True, rotations=ROTATIONS),
+        "Z2_3": PipelineConfig(sfc="FZ", shift=True, uneven_prime=True,
+                               bandwidth_scale=True, box=(2, 2, 8),
+                               rotations=ROTATIONS),
     }
     for name, mc in variants.items():
-        res = Mapper(mc).map(graph, alloc, task_coords=tc)
+        res = MappingPipeline(mc).map(graph, alloc, task_coords=tc)
         out[name] = evaluate(graph, alloc, res)
     return out
 

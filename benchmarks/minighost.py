@@ -15,7 +15,7 @@ match: Default's hops/latency GROW with core count; Z2_1/Z2_2 stay ~flat
 (the scalability claim); Z2_3 trades higher hops for lower bottleneck
 Latency; geometric mappings beat Default by large factors at 128K.
 
-All Z2 variants run through ``repro.core.Mapper`` -> the unified
+All Z2 variants run through ``MappingPipeline``, the unified
 ``repro.mapping`` pipeline (vectorised partitioner + shared candidate
 search).
 """
@@ -26,9 +26,9 @@ import time
 
 import numpy as np
 
-from repro.core import (Mapper, MapperConfig, MappingResult, evaluate,
-                        gemini_xk7, identity_mapping, sfc_allocation,
-                        stencil_graph)
+from repro.core import (MappingResult, evaluate, gemini_xk7,
+                        identity_mapping, sfc_allocation, stencil_graph)
+from repro.mapping import MappingPipeline, PipelineConfig
 
 TASK_GRIDS = {
     8192: (32, 16, 16),
@@ -70,16 +70,17 @@ def run_point(ncores: int, seed: int, *, nfragments: int = 8) -> dict:
     mappers = {
         "Default": None,
         "Group": "group",
-        "Z2_1": Mapper(MapperConfig(sfc="FZ", shift=True,
-                                    rotations=ROTATIONS)),
-        "Z2_2": Mapper(MapperConfig(sfc="FZ", shift=True,
-                                    bandwidth_scale=True,
-                                    uneven_prime=True,
-                                    rotations=ROTATIONS)),
-        "Z2_3": Mapper(MapperConfig(sfc="FZ", shift=True,
-                                    bandwidth_scale=True,
-                                    uneven_prime=True, box=(2, 2, 8),
-                                    rotations=ROTATIONS)),
+        "Z2_1": MappingPipeline(PipelineConfig(sfc="FZ", shift=True,
+                                               rotations=ROTATIONS)),
+        "Z2_2": MappingPipeline(PipelineConfig(sfc="FZ", shift=True,
+                                               bandwidth_scale=True,
+                                               uneven_prime=True,
+                                               rotations=ROTATIONS)),
+        "Z2_3": MappingPipeline(PipelineConfig(sfc="FZ", shift=True,
+                                               bandwidth_scale=True,
+                                               uneven_prime=True,
+                                               box=(2, 2, 8),
+                                               rotations=ROTATIONS)),
     }
     out = {}
     for name, mapper in mappers.items():

@@ -271,9 +271,10 @@ def main() -> None:
         proc_perm) candidates, mapped onto a 4096-node (16,16,16) torus
         (16 tasks per processor) under strict dimension alternation —
         the setting where a rotation IS the cut order, so the sweep is
-        the search.  ``sweep="batched"`` partitions the whole sweep in
-        ~2 engine passes over the unique per-side permutations;
-        ``sweep="loop"`` is the 2-partitions-per-candidate oracle.  All
+        the search.  ``map_candidates`` partitions the whole sweep in
+        ~2 engine passes over the unique per-side permutations; a
+        ``map_candidate`` call per candidate is the
+        2-partitions-per-candidate oracle ("loop").  All
         24 mappings must be bit-identical between the paths (hence so
         is the scored winner, asserted too); the speedup floor guards
         the batched path against regression (ISSUE 2: >=5x here).
@@ -302,19 +303,20 @@ def main() -> None:
         tc = graph.coords.astype(np.float64)
         cands = rotation_candidates(3, 3, rotations)
         assert graph.n == n and len(cands) == rotations
-        pipes = {
-            s: MappingPipeline(PipelineConfig(
-                sfc="FZ", shift=True, rotations=rotations,
-                longest_dim=False, sweep=s,
-                score_backend=SCORE_BACKEND,
-                partition_backend=PARTITION_BACKEND))
-            for s in ("loop", "batched")
-        }
-        pc = pipes["loop"].machine_coords(alloc)
+        pipe = MappingPipeline(PipelineConfig(
+            sfc="FZ", shift=True, rotations=rotations, longest_dim=False,
+            score_backend=SCORE_BACKEND,
+            partition_backend=PARTITION_BACKEND))
+        pc = pipe.machine_coords(alloc)
 
         def sweep(mode):
             t0 = time.perf_counter()
-            res = pipes[mode].map_candidates(tc, pc, cands)
+            if mode == "loop":
+                res = [pipe.map_candidate(tc, pc, task_perm=c.task_perm,
+                                          proc_perm=c.proc_perm)
+                       for c in cands]
+            else:
+                res = pipe.map_candidates(tc, pc, cands)
             return time.perf_counter() - t0, res
 
         sweep("batched")  # warm both engine paths at full size once
@@ -334,8 +336,8 @@ def main() -> None:
         for rl, rb in zip(res_loop, res_bat):
             assert np.array_equal(rl.task_to_proc, rb.task_to_proc), \
                 "batched sweep mapping differs from the loop oracle"
-        best_l, i_l, _ = pipes["loop"].search.best(graph, alloc, res_loop)
-        best_b, i_b, _ = pipes["batched"].search.best(graph, alloc, res_bat)
+        best_l, i_l, _ = pipe.search.best(graph, alloc, res_loop)
+        best_b, i_b, _ = pipe.search.best(graph, alloc, res_bat)
         assert i_l == i_b and np.array_equal(best_l.task_to_proc,
                                              best_b.task_to_proc), \
             "scored winner differs between sweep modes"

@@ -17,9 +17,9 @@ Run:  PYTHONPATH=src python examples/hier_demo.py
 
 import time
 
-from repro.core import (Mapper, MapperConfig, evaluate, gemini_xk7,
-                        identity_mapping, sfc_allocation, stencil_graph)
-from repro.hier import HierarchySpec
+from repro.core import (evaluate, gemini_xk7, identity_mapping,
+                        sfc_allocation, stencil_graph)
+from repro.mapping import HierarchySpec, MappingPipeline, PipelineConfig
 
 
 def main():
@@ -35,8 +35,8 @@ def main():
              ("depth3", HierarchySpec.with_depth(3)))
     results = {}
     for name, spec in specs:
-        mapper = Mapper(MapperConfig(sfc="FZ", shift=True, rotations=8,
-                                     hierarchy=spec))
+        mapper = MappingPipeline(PipelineConfig(
+            sfc="FZ", shift=True, rotations=8, hierarchy=spec))
         t0 = time.perf_counter()
         res = mapper.map(app, alloc)
         dt = time.perf_counter() - t0

@@ -7,8 +7,9 @@ mapping vs the geometric (MJ + Flipped-Z) mapping.
 Run:  PYTHONPATH=src python examples/quickstart.py
 """
 
-from repro.core import (Mapper, MapperConfig, evaluate, gemini_xk7,
-                        identity_mapping, sfc_allocation, stencil_graph)
+from repro.core import (evaluate, gemini_xk7, identity_mapping,
+                        sfc_allocation, stencil_graph)
+from repro.mapping import MappingPipeline, PipelineConfig
 
 
 def main():
@@ -26,8 +27,8 @@ def main():
     # Geometric mapping (paper Alg. 1): Multi-Jagged partitioning of task
     # and machine coordinates with Flipped-Z part numbering, torus
     # shifting, and bandwidth-scaled node coordinates.
-    mapper = Mapper(MapperConfig(sfc="FZ", shift=True,
-                                 bandwidth_scale=True))
+    mapper = MappingPipeline(PipelineConfig(sfc="FZ", shift=True,
+                                            bandwidth_scale=True))
     ours = evaluate(app, alloc, mapper.map(app, alloc))
 
     print(f"{'metric':>18s} {'default':>12s} {'geometric':>12s}")

@@ -8,8 +8,7 @@ from .machine import (Allocation, Machine, bgq, block_allocation,
                       gemini_xk7, make_machine, random_allocation,
                       sfc_allocation, tpu_v4_cube, tpu_v5e_multipod,
                       tpu_v5e_pod)
-from .mapping import (Mapper, MapperConfig, MappingResult, evaluate,
-                      geometric_map, identity_mapping)
+from .mapping import MappingResult, evaluate, identity_mapping
 from .metrics import (Traffic, average_hops, data_metric,
                       evaluate_candidates, evaluate_mapping, latency_metric,
                       pairwise_hops, per_dim_stats, route_traffic,
@@ -28,14 +27,14 @@ from .transforms import (apply_permutation, box_lift, drop_dims,
                          shift_torus)
 
 __all__ = [
-    "Allocation", "BACKENDS", "Machine", "Mapper", "MapperConfig",
-    "MappingResult", "SFC_KINDS", "TaskGraph", "Traffic",
+    "Allocation", "BACKENDS", "Machine", "MappingResult", "SFC_KINDS",
+    "TaskGraph", "Traffic",
     "allocation_signature", "array_digest",
     "apply_permutation", "average_hops", "bgq", "block_allocation",
     "box_lift", "closest_subset", "config_signature", "cube_coords",
     "cube_sphere_graph",
     "data_metric", "drop_dims", "evaluate", "evaluate_candidates",
-    "evaluate_mapping", "face2d_coords", "gemini_xk7", "geometric_map",
+    "evaluate_mapping", "face2d_coords", "gemini_xk7",
     "gray_decode", "gray_encode", "grid_order", "hilbert_index",
     "hilbert_key", "identity_mapping", "latency_metric",
     "logical_mesh_graph",

@@ -30,20 +30,19 @@ hierarchical process mapping):
 
 Select it with ``PipelineConfig(hierarchy=HierarchySpec.node())`` /
 ``.with_depth(3)`` / ``.from_machine(machine, depth)`` (or the same
-kwarg on ``MapperConfig`` / ``select_mapping``); the strings ``"flat"``
-/ ``"node"`` remain as deprecated aliases and ``"depth<N>"`` as sugar.
-The ``hier`` benchmark entry compares flat, depth-2 and depth-3.
+kwarg on ``select_mapping``).  The ``hier`` benchmark entry compares
+flat, depth-2 and depth-3.
 """
 
 from .aggregate import Aggregation, aggregate_tasks
 from .levels import group_units, map_hierarchical, router_view
 from .refine import (assign_cores, hilbert_key, polish_groups, refine_qap,
                      refine_swaps)
-from .spec import HierarchySpec, Level, normalize_config_hierarchy
+from .spec import HierarchySpec, Level
 
 __all__ = [
     "Aggregation", "HierarchySpec", "Level", "aggregate_tasks",
     "assign_cores", "group_units", "hilbert_key", "map_hierarchical",
-    "normalize_config_hierarchy", "polish_groups", "refine_qap",
+    "polish_groups", "refine_qap",
     "refine_swaps", "router_view",
 ]

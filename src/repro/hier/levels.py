@@ -27,10 +27,9 @@ mapping):
    with :func:`repro.hier.refine.assign_cores` (children dealt onto
    their group's member units in SFC order), until tasks sit on cores.
 
-A depth-2 spec follows PR 3's exact code path — same calls, same
-arguments, same order — so ``HierarchySpec.node()`` reproduces the
-legacy ``hierarchy="node"`` results bit for bit (winners AND refine
-trajectory; asserted in tests/test_hierarchy_spec.py).  Because every
+A depth-2 spec (``HierarchySpec.node()``) is the classic two-level
+node map: coarsen to node-sized clusters, sweep at router granularity,
+refine once, expand.  Because every
 task inherits its node's router coordinates, the level-1 refined score
 equals the fine mapping's volume-weighted metrics exactly, whatever
 the depth.

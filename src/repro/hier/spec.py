@@ -1,13 +1,11 @@
 """Structured description of the mapping hierarchy (ISSUE 10).
 
-A :class:`HierarchySpec` replaces the old ``hierarchy="flat"|"node"``
-string plus flat ``refine_rounds/top/degree`` knobs of
-``PipelineConfig``/``MapperConfig``: it is an ordered tuple of
-:class:`Level` coarsening steps (fine -> coarse), each carrying its own
-group arity and refinement budget, so the N-level recursive hierarchy
-of Schulz & Woydt's shared-memory process mapping — pod -> rack ->
-node -> socket -> core — becomes a first-class, content-addressable
-config value.
+A :class:`HierarchySpec` is the ``hierarchy`` of a ``PipelineConfig``:
+an ordered tuple of :class:`Level` coarsening steps (fine -> coarse),
+each carrying its own group arity and refinement budget, so the
+N-level recursive hierarchy of Schulz & Woydt's shared-memory process
+mapping — pod -> rack -> node -> socket -> core — becomes a
+first-class, content-addressable config value.
 
 Depth terminology (``spec.depth == 1 + len(spec.levels)``):
 
@@ -20,9 +18,9 @@ Depth terminology (``spec.depth == 1 + len(spec.levels)``):
   above the node level; each level divides the top-sweep point count
   by its ``arity`` and gets its own refinement pass on the way down.
 
-The legacy strings keep working as deprecated aliases through
-:meth:`HierarchySpec.from_string` (``PipelineConfig`` calls it when
-handed a string); :meth:`HierarchySpec.from_machine` derives the node
+:meth:`HierarchySpec.from_string` parses the names the scenario
+registry uses ("flat", "node", "depthN");
+:meth:`HierarchySpec.from_machine` derives the node
 arity from a :class:`repro.core.Machine`'s core dims.  Specs are frozen
 dataclasses, so :func:`repro.core.signature.config_signature`
 canonicalises them field by field — two equal specs built through
@@ -35,11 +33,10 @@ from __future__ import annotations
 import dataclasses
 import re
 
-__all__ = ["Level", "HierarchySpec", "HIERARCHY_ALIASES",
-           "normalize_config_hierarchy"]
+__all__ = ["Level", "HierarchySpec", "HIERARCHY_NAMES"]
 
 # arity of grouping levels ABOVE the node level when not given
-# explicitly ("depth3"/"depth4" aliases, from_machine defaults).  4
+# explicitly ("depth3"/"depth4" names, from_machine defaults).  4
 # units per group still cuts the top sweep 4x per level, and smaller
 # groups keep the medoid abstraction honest: the expansion error the
 # intra-group polish must repair grows with group radius, and at arity
@@ -47,10 +44,8 @@ __all__ = ["Level", "HierarchySpec", "HIERARCHY_ALIASES",
 # the hier benchmark (arity 8 leaves ~6%, arity 16 ~13%).
 DEFAULT_GROUP_ARITY = 4
 
-# refinement-budget defaults — EXACTLY the old PipelineConfig
-# refine_rounds/refine_top/refine_degree defaults, so
-# ``HierarchySpec.node()`` is bit-identical to the legacy
-# ``hierarchy="node"`` path.
+# refinement-budget defaults of the node level (the classic two-level
+# node map that ``HierarchySpec.node()`` builds).
 DEFAULT_REFINE_ROUNDS = 2
 DEFAULT_REFINE_TOP = 64
 DEFAULT_REFINE_DEGREE = 4
@@ -63,12 +58,12 @@ DEFAULT_QAP_ROUNDS = 1
 
 # rounds of the intra-group polish pass run when a GROUP-level
 # assignment is expanded one level down (depth >= 3 only; a depth-2
-# spec has no group expansion, so the legacy path never sees it).  The
+# spec has no group expansion, so the node map never sees it).  The
 # per-round gain decays geometrically; 4 rounds capture ~90% of the
 # converged improvement at a fraction of the cost.
 DEFAULT_POLISH_ROUNDS = 4
 
-HIERARCHY_ALIASES = ("flat", "node", "depth<N>")
+HIERARCHY_NAMES = ("flat", "node", "depth<N>")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,7 +96,7 @@ class Level:
                     inside every group at once, repairing the member
                     placements the medoid abstraction could not see.
                     Only reachable at depth >= 3 (depth 2 has no group
-                    expansion), so it never perturbs the legacy path.
+                    expansion), so it never perturbs the node map.
     """
 
     name: str = "node"
@@ -198,9 +193,7 @@ class HierarchySpec:
              refine_degree: int = DEFAULT_REFINE_DEGREE,
              refine_mode: str = "swap") -> "HierarchySpec":
         """PR 3's two-level node-granularity hierarchy (depth 2), with
-        the node arity derived from the machine at map time.  Default
-        budgets reproduce the legacy ``hierarchy="node"`` path bit for
-        bit."""
+        the node arity derived from the machine at map time."""
         return cls((Level("node", None, refine_rounds, refine_top,
                           refine_degree, refine_mode),))
 
@@ -225,8 +218,7 @@ class HierarchySpec:
         two swap rounds after the polish buy < 0.1% quality for ~40%
         of depth-2's whole runtime) and grouping levels run
         :data:`DEFAULT_QAP_ROUNDS` of the QAP search.  An explicit
-        ``refine_rounds`` applies to every level (the landing pad for
-        the deprecated flat ``refine_rounds=`` kwarg)."""
+        ``refine_rounds`` applies to every level."""
         if n < 1:
             raise ValueError(f"depth must be >= 1, got {n}")
         if n == 1:
@@ -246,29 +238,19 @@ class HierarchySpec:
         return cls(tuple(levels))
 
     @classmethod
-    def from_string(cls, name: str, *,
-                    refine_rounds: int | None = None,
-                    refine_top: int | None = None,
-                    refine_degree: int | None = None) -> "HierarchySpec":
-        """Parse a hierarchy alias: ``"flat"``, ``"node"`` or
-        ``"depthN"`` (N >= 1).  The optional refine knobs override every
-        level's budget — this is the landing pad for the deprecated
-        flat ``refine_*`` config fields."""
-        kw = {k: int(v) for k, v in (("refine_rounds", refine_rounds),
-                                     ("refine_top", refine_top),
-                                     ("refine_degree", refine_degree))
-              if v is not None}
+    def from_string(cls, name: str) -> "HierarchySpec":
+        """Parse a hierarchy name of the scenario registry:
+        ``"flat"``, ``"node"`` or ``"depthN"`` (N >= 1)."""
         if name == "flat":
             return cls.flat()
         if name == "node":
-            return cls.node(**kw)
+            return cls.node()
         m = _DEPTH_RE.match(name)
         if m:
-            return cls.with_depth(int(m.group(1)), **kw)
+            return cls.with_depth(int(m.group(1)))
         raise ValueError(
             f"unknown hierarchy {name!r}; accepted: "
-            f"{', '.join(repr(a) for a in HIERARCHY_ALIASES)} "
-            f"or a HierarchySpec")
+            f"{', '.join(repr(a) for a in HIERARCHY_NAMES)}")
 
     @classmethod
     def from_machine(cls, machine, depth: int = 2, *,
@@ -281,7 +263,7 @@ class HierarchySpec:
         level's arity is the machine's cores-per-node (product of its
         core dims), deeper levels use ``group_arity``.  Machines
         without core dims keep ``arity=None`` (one cluster per router,
-        like the legacy path)."""
+        like :meth:`node`)."""
         spec = cls.with_depth(depth, group_arity=group_arity,
                               refine_rounds=refine_rounds,
                               refine_top=refine_top,
@@ -296,7 +278,7 @@ class HierarchySpec:
             spec = cls(levels)
         return spec
 
-    # -- derived specs (resilience ladder & shims) ----------------------
+    # -- derived specs (resilience ladder) -------------------------------
 
     def truncated(self, depth: int) -> "HierarchySpec":
         """The same spec cut down to ``depth`` granularities (the
@@ -328,59 +310,3 @@ class HierarchySpec:
         """Total polish budget — nonzero only matters at depth >= 3."""
         return sum(lv.polish_rounds for lv in self.levels)
 
-
-# -- the config-side deprecation shim ------------------------------------
-
-_LEGACY_REFINE_FIELDS = ("refine_rounds", "refine_top", "refine_degree")
-
-
-def normalize_config_hierarchy(config) -> None:
-    """Shared ``__post_init__`` body of ``PipelineConfig`` and
-    ``MapperConfig``: normalise ``config.hierarchy`` to a
-    :class:`HierarchySpec` and fold the deprecated flat ``refine_*``
-    knobs into it.
-
-    Validation happens HERE — at config construction — so a bad
-    hierarchy raises a 4xx-style ``ValueError`` listing the accepted
-    values before any mapping work starts (in particular before the
-    serve layer admits the request or burns a degradation-ladder
-    rung).  The deprecation shim emits a single
-    :class:`DeprecationWarning` per construction naming the
-    replacement; re-normalising an already-normalised config
-    (``dataclasses.replace`` re-runs this) is a silent no-op.
-    """
-    import warnings
-
-    legacy = {k: getattr(config, k) for k in _LEGACY_REFINE_FIELDS
-              if getattr(config, k) is not None}
-    h = config.hierarchy
-    if isinstance(h, str):
-        spec = HierarchySpec.from_string(h, **legacy)  # raises on junk
-        if h != "flat" or legacy:
-            warnings.warn(
-                f"hierarchy={h!r}"
-                + (f" with {'/'.join(sorted(legacy))}=" if legacy else "")
-                + " is deprecated; pass hierarchy=HierarchySpec"
-                ".from_string(...) (or .node()/.with_depth()/"
-                ".from_machine()) with per-level refine budgets instead",
-                DeprecationWarning, stacklevel=4)
-    elif isinstance(h, HierarchySpec):
-        spec = h
-        if legacy:
-            warnings.warn(
-                f"the flat {'/'.join(sorted(legacy))} config fields are "
-                "deprecated; set the refine budgets on the "
-                "HierarchySpec levels instead",
-                DeprecationWarning, stacklevel=4)
-            spec = spec.with_refine(
-                rounds=legacy.get("refine_rounds"),
-                top=legacy.get("refine_top"),
-                degree=legacy.get("refine_degree"))
-    else:
-        raise ValueError(
-            f"unknown hierarchy {h!r}; accepted: "
-            f"{', '.join(repr(a) for a in HIERARCHY_ALIASES)} "
-            f"or a HierarchySpec")
-    config.hierarchy = spec
-    for k in _LEGACY_REFINE_FIELDS:  # folded into the spec above
-        setattr(config, k, None)

@@ -7,17 +7,18 @@ decomposed into pluggable stages:
 1. **machine transforms** — torus shifting, bandwidth scaling, dim
    drops, box lifts (:meth:`MappingPipeline.machine_coords`);
 2. **partitioner backend** — the level-synchronous vectorised
-   Multi-Jagged engine (:mod:`repro.core.partition`) or the recursive
-   reference, selected per call;
+   Multi-Jagged engine, on the host (:mod:`repro.core.partition`) or
+   on device (:mod:`repro.core.partition_jax`);
 3. **part matching** — equal part numbers task<->processor, including
    the tnum<pnum closest-subset case (:func:`match_parts`);
 4. **candidate search** — one batched engine scoring every rotation /
    coordinate-scaling candidate with vectorised ``weighted_hops`` and
    per-link traffic evaluation (:class:`CandidateSearch`).
 
-``repro.core.mapping.Mapper`` (the paper's Z2), ``repro.meshmap``'s
-``topology_mesh``/``select_mapping`` and all benchmarks delegate here —
-there is exactly one rotation/candidate-search loop in the codebase.
+``MappingPipeline(PipelineConfig(...))`` is the paper's Z2 mapper;
+``repro.meshmap``'s ``topology_mesh``/``select_mapping``, the serve
+layer and all benchmarks run through it — there is exactly one
+rotation/candidate-search loop in the codebase.
 """
 
 from .candidates import Candidate, CandidateSearch, rotation_candidates

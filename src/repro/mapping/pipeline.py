@@ -1,27 +1,26 @@
 """The mapping pipeline: machine transforms -> partitioner -> matching
 -> candidate scoring (paper Alg. 1 + §4.3, one engine for every caller).
 
-``MappingPipeline`` owns the full Z2-style flow that used to be split
-(and partially duplicated) between ``core/mapping.py::Mapper`` and
-``meshmap/device_mesh.py::select_mapping``:
+``MappingPipeline`` owns the full Z2-style flow (the paper's mapper;
+``meshmap.select_mapping`` and the serve layer call it too):
 
 - :meth:`machine_coords` applies the machine-side transforms (core-dim
   drop, torus shift, bandwidth scaling, "+E" dim drops, box lift);
 - :meth:`map_candidate` runs one geometric mapping (Algorithm 1) for a
   single rotation/scaling candidate through the level-synchronous
-  vectorised partitioner (``backend`` selects the engine);
+  vectorised partitioner (on device when ``partition_backend``
+  resolves to "jax");
 - :meth:`map_candidates` runs a WHOLE rotation sweep in ~2 batched
-  engine passes (``sweep="batched"``, the default): the unique task and
-  processor dimension permutations become outermost segments of one
-  ``order_points_batched`` call per side, bit-identical to the
-  per-candidate loop (``sweep="loop"``, kept as the oracle);
+  engine passes: the unique task and processor dimension permutations
+  become outermost segments of one ``order_points_batched`` call per
+  side, bit-identical to calling :meth:`map_candidate` per candidate
+  (the oracle the tests compare against);
 - :meth:`map` enumerates the rotation candidates and scores them with
   the batched :class:`repro.mapping.candidates.CandidateSearch`
   (``score_backend`` selects numpy or the jit-compiled JAX scorer).
 
-``core.mapping.Mapper`` and ``meshmap.select_mapping`` are thin
-adapters over this class; benchmarks therefore all route through one
-search implementation.
+:func:`fused_engages` is the one rule deciding whether a config runs
+the fused whole-pipeline program (:mod:`repro.mapping.fused`).
 """
 
 from __future__ import annotations
@@ -35,12 +34,12 @@ from repro import obs
 from repro.core.kmeans import closest_subset
 from repro.core.machine import Allocation
 from repro.core.mapping import MappingResult, match_parts
+from repro.core.metrics import get_evaluator
 from repro.core.orderings import (order_points, order_points_batched,
                                   resolve_partition_backend)
 from repro.core.transforms import (apply_permutation, box_lift, drop_dims,
                                    scale_by_bandwidth, shift_torus)
-from repro.hier.spec import (HierarchySpec, Level,
-                             normalize_config_hierarchy)
+from repro.hier.spec import HierarchySpec, Level
 from repro.mapping.candidates import CandidateSearch, rotation_candidates
 
 
@@ -54,16 +53,15 @@ class PipelineConfig:
       longest_dim  : cut the longest dimension (False = strict
                      alternation).
       uneven_prime : Z2_2 — largest-prime-divisor uneven bisection.
-      backend      : ``order_points`` backend ("vectorized"/"recursive").
-      partition_backend : partition engine — "numpy" (host reference)
-                     or "jax" (device ``partition_jax`` engine,
-                     bit-identical permutations, resolved ONCE per
-                     pipeline down the silent jax -> numpy chain).
-                     With a jax/pallas score backend and the batched
-                     vectorized sweep, the whole partition -> match ->
+      partition_backend : partition engine — "numpy" (the host
+                     vectorised engine) or "jax" (device
+                     ``partition_jax`` engine, bit-identical
+                     permutations, resolved ONCE per pipeline down the
+                     silent jax -> numpy chain).  With a jax/pallas
+                     score backend the whole partition -> match ->
                      score -> select chain fuses into ONE compiled
                      program per candidate stack
-                     (:mod:`repro.mapping.fused`).
+                     (:mod:`repro.mapping.fused`, :func:`fused_engages`).
       fused        : "auto" (default) engages the fused whole-pipeline
                      program whenever the backends allow it; "off"
                      forces the unfused staged path — the first rung of
@@ -82,12 +80,6 @@ class PipelineConfig:
                   (task_perm, proc_perm) pairs evaluated.
       objective : metric key (or tuple, lexicographic) minimised by the
                   search; "weighted_hops" is the paper's choice.
-      sweep     : "batched" partitions every rotation of a sweep in ~2
-                  engine passes (one task-side, one proc-side batched
-                  ``order_points_batched`` call over the UNIQUE
-                  permutations of each side); "loop" runs
-                  ``map_candidate`` per candidate — the bit-identical
-                  oracle the ``candidates`` benchmark times against.
       score_backend : candidate scoring engine — "numpy" (default),
                   "jax" (jit-compiled, message counts bucketed to
                   power-of-two shapes so scenarios compile O(1) times)
@@ -106,13 +98,9 @@ class PipelineConfig:
                   ``HierarchySpec.with_depth(n)`` adds geometric
                   grouping levels above the node level, each dividing
                   the top-sweep point count by its arity and refining
-                  on the way down.  The legacy strings "flat"/"node"
-                  are accepted as deprecated aliases (normalised to
-                  the equivalent spec with one DeprecationWarning).
-      refine_rounds / refine_top / refine_degree : DEPRECATED — the old
-                  flat refinement knobs.  When set (non-None) they fold
-                  into every level of the spec and warn; use the
-                  per-level ``Level`` budgets instead.
+                  on the way down.  Any other value raises
+                  ``ValueError`` when the config is built, before any
+                  mapping work or serve admission.
     """
 
     sfc: str = "FZ"
@@ -125,19 +113,34 @@ class PipelineConfig:
     rotations: int = 0
     uneven_prime: bool = False
     longest_dim: bool = True
-    backend: str = "vectorized"
     partition_backend: str = "numpy"
     fused: str = "auto"
     objective: str | tuple = "weighted_hops"
-    sweep: str = "batched"
     score_backend: str = "numpy"
-    hierarchy: HierarchySpec | str = "flat"
-    refine_rounds: int | None = None
-    refine_top: int | None = None
-    refine_degree: int | None = None
+    hierarchy: HierarchySpec = HierarchySpec.flat()
 
     def __post_init__(self):
-        normalize_config_hierarchy(self)
+        if not isinstance(self.hierarchy, HierarchySpec):
+            raise ValueError(
+                f"hierarchy must be a HierarchySpec, got "
+                f"{self.hierarchy!r}; build one with HierarchySpec.flat()"
+                f" / .node() / .with_depth(n), or parse a name with "
+                f"HierarchySpec.from_string")
+
+
+def fused_engages(config: PipelineConfig) -> bool:
+    """Does ``config`` run the fused whole-pipeline program?
+
+    True when ``fused`` is not "off", the partition backend RESOLVES to
+    "jax" and the score backend to "jax" or "pallas" (so a machine
+    without jax never engages it).  :class:`MappingPipeline` and the
+    serve layer's degradation ladder both decide by this rule.
+    """
+    if config.fused == "off":
+        return False
+    if resolve_partition_backend(config.partition_backend) != "jax":
+        return False
+    return get_evaluator(config.score_backend)[0] in ("jax", "pallas")
 
 
 # Process-wide pipeline registry: one MappingPipeline per distinct
@@ -185,21 +188,15 @@ class MappingPipeline:
         # on plain strings instead of re-probing the import per call
         self.partition_backend = resolve_partition_backend(
             cfg.partition_backend)
-        self.order_backend = ("jax" if (self.partition_backend == "jax"
-                                        and cfg.backend == "vectorized")
-                              else cfg.backend)
+        self.order_backend = ("jax" if self.partition_backend == "jax"
+                              else "vectorized")
         # fused whole-pipeline program: partition + match + score +
-        # select as ONE compiled program per candidate stack — only when
-        # both stages resolved to device backends and the sweep is the
-        # batched vectorized one (the fused gathers mirror it exactly)
+        # select as ONE compiled program per candidate stack
         self._fused = None
-        if (cfg.fused != "off" and self.order_backend == "jax"
-                and cfg.sweep == "batched"):
-            from repro.core.metrics import get_evaluator
-            resolved_score, _ = get_evaluator(cfg.score_backend)
-            if resolved_score in ("jax", "pallas"):
-                from repro.mapping.fused import FusedSweep
-                self._fused = FusedSweep(self, resolved_score)
+        if fused_engages(cfg):
+            from repro.mapping.fused import FusedSweep
+            self._fused = FusedSweep(
+                self, get_evaluator(cfg.score_backend)[0])
 
     # -- stage 1: machine transforms ------------------------------------
 
@@ -304,30 +301,28 @@ class MappingPipeline:
         proc-side permutations in one ``order_points_batched`` call per
         side — ~2 engine passes for the whole sweep — and assembles the
         per-candidate ``task_to_proc`` arrays with one vectorised
-        part-matching gather.  Results are bit-identical to the
-        ``sweep="loop"`` per-candidate path (guarded by the
+        part-matching gather.  Results are bit-identical to calling
+        :meth:`map_candidate` per candidate (guarded by the
         ``candidates`` benchmark and tests/test_batched.py).
 
-        Falls back to the loop only for the tnum < pnum closest-subset
-        case (the subset's centroid iteration sums coordinates in
-        column order).  Hilbert batches too: ``order_points_batched``
-        treats each ``dim_orders`` row as a COLUMN permutation of the
-        cloud (quantisation commutes with it), bit-identical to the
-        per-candidate loop.
+        Takes that per-candidate path itself for a single candidate and
+        for the tnum < pnum closest-subset case (the subset's centroid
+        iteration sums coordinates in column order).  Hilbert batches
+        too: ``order_points_batched`` treats each ``dim_orders`` row as
+        a COLUMN permutation of the cloud (quantisation commutes with
+        it), bit-identical to the per-candidate loop.
         """
         cfg = self.config
         tc = np.asarray(task_coords, dtype=np.float64)
         pc = np.asarray(proc_coords, dtype=np.float64)
         (tnum, td), (pnum, pd) = tc.shape, pc.shape
-        if cfg.sweep == "loop" or len(cands) == 1 or tnum < pnum:
+        if len(cands) == 1 or tnum < pnum:
             return [
                 self.map_candidate(tc, pc, task_weights=task_weights,
                                    task_perm=c.task_perm,
                                    proc_perm=c.proc_perm)
                 for c in cands
             ]
-        if cfg.sweep != "batched":
-            raise ValueError(f"unknown sweep mode {cfg.sweep!r}")
 
         np_parts = min(tnum, pnum)
         task_sfc, proc_sfc = self._sfc_pair(td, pd)
@@ -385,10 +380,6 @@ class MappingPipeline:
         downward one level at a time with a refinement pass per level.
         """
         cfg = self.config
-        if not isinstance(cfg.hierarchy, HierarchySpec):
-            # configs are validated at construction; this only fires
-            # when a caller mutates cfg.hierarchy afterwards
-            normalize_config_hierarchy(cfg)
         if not cfg.hierarchy.is_flat:
             from repro.hier.levels import map_hierarchical
             return map_hierarchical(self, graph, alloc,

@@ -5,9 +5,9 @@ The logical mesh (e.g. ("pod","data","model")) is the *task graph*: jit
 emits collectives per axis, with very different traffic weights (TP
 all-reduces per layer on "model" >> FSDP gathers on "data" >> cross-DCN
 on "pod").  The physical chips form the *machine graph* (ICI torus +
-slow DCN dim).  geometric_map (MJ + FZ ordering, Alg. 1) consistently
-orders both and hands us the device permutation that puts heavy-traffic
-logical neighbours on adjacent chips.
+slow DCN dim).  The geometric mapping (MJ + FZ ordering, Alg. 1)
+consistently orders both and hands us the device permutation that puts
+heavy-traffic logical neighbours on adjacent chips.
 
 On real TPU backends the chip coordinates come from device attributes;
 on this CPU container they are synthesised from the machine model (the
@@ -24,7 +24,8 @@ from jax.sharding import Mesh
 from repro.core import (Allocation, block_allocation, evaluate,
                         identity_mapping, logical_mesh_graph,
                         tpu_v5e_multipod, tpu_v5e_pod)
-from repro.mapping import CandidateSearch, PipelineConfig, shared_pipeline
+from repro.mapping import (CandidateSearch, HierarchySpec, PipelineConfig,
+                           shared_pipeline)
 
 # Relative per-link traffic of one training step along each logical axis
 # (bytes are arbitrary units; only ratios steer the mapper).
@@ -66,7 +67,8 @@ def topology_mesh(axis_sizes: tuple[int, ...], axis_names: tuple[str, ...],
                   rotations: int = 16, return_report: bool = False,
                   score_backend: str = "numpy",
                   partition_backend: str = "numpy",
-                  hierarchy="flat", sfc: str = "FZ", service=None):
+                  hierarchy: HierarchySpec = HierarchySpec.flat(),
+                  sfc: str = "FZ", service=None):
     """Build a Mesh whose device order minimises modeled link traffic.
 
     Candidate-selection (the paper's §4.3 rotation search, generalised):
@@ -110,7 +112,8 @@ def topology_mesh(axis_sizes: tuple[int, ...], axis_names: tuple[str, ...],
 def select_mapping(graph, alloc, axis_bytes, *, rotations: int = 16,
                    score_backend: str = "numpy",
                    partition_backend: str = "numpy",
-                   hierarchy="flat", sfc: str = "FZ",
+                   hierarchy: HierarchySpec = HierarchySpec.flat(),
+                   sfc: str = "FZ",
                    service=None):
     """Candidate search: default order + SFC-geometric mappings (``sfc``
     picks the part numbering — "FZ" is the paper's winner, "H" the
@@ -139,8 +142,8 @@ def select_mapping(graph, alloc, axis_bytes, *, rotations: int = 16,
     benchmark guards.
 
     ``hierarchy`` takes a :class:`repro.hier.HierarchySpec`
-    (``HierarchySpec.node()``, ``.with_depth(3)``, ``.from_machine``;
-    the strings "flat"/"node" remain deprecated aliases) and routes
+    (``HierarchySpec.node()``, ``.with_depth(3)``, ``.from_machine``)
+    and routes
     each pipeline call through the recursive coarsen* -> map ->
     refine/expand* subsystem (:mod:`repro.hier`) — worthwhile on
     machines with core dims or very large logical meshes; on a machine

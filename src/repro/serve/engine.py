@@ -127,8 +127,8 @@ def make_request(graph, alloc, objective="wh", *, config=None,
     ``hierarchy=HierarchySpec.node()``, ...); pass ``config`` to supply
     a full config instead (mutually exclusive with
     ``objective``/``overrides``).  Config validation happens at
-    CONSTRUCTION — an unknown hierarchy raises a 4xx-style
-    ``ValueError`` (listing the accepted values) here, before the
+    CONSTRUCTION — a hierarchy that is not a ``HierarchySpec`` raises
+    a 4xx-style ``ValueError`` here, before the
     request is ever admitted to the service or burns a
     degradation-ladder rung.
     """

@@ -65,7 +65,7 @@ import time
 from repro import obs
 from repro.core.metrics import _BACKEND_CHAIN, get_evaluator
 from repro.core.orderings import resolve_partition_backend
-from repro.mapping import PipelineConfig
+from repro.mapping.pipeline import PipelineConfig, fused_engages
 
 
 class ServiceOverloaded(RuntimeError):
@@ -194,26 +194,11 @@ class BreakerBoard:
 
 # -- the degradation ladder ----------------------------------------------
 
-def fused_candidate(config: PipelineConfig) -> bool:
-    """Would this config engage the fused whole-pipeline program?
-
-    Mirrors :class:`MappingPipeline`'s construction gate (including
-    backend RESOLUTION, so a machine without jax never gets a spurious
-    ``unfused`` rung).
-    """
-    if (config.fused == "off" or config.sweep != "batched"
-            or config.backend != "vectorized"):
-        return False
-    if resolve_partition_backend(config.partition_backend) != "jax":
-        return False
-    return get_evaluator(config.score_backend)[0] in ("jax", "pallas")
-
-
 def rung_key(config: PipelineConfig) -> str:
     """The breaker key: which RESOLVED backends serve this config."""
     score = get_evaluator(config.score_backend)[0]
     part = resolve_partition_backend(config.partition_backend)
-    parts = ["fused" if fused_candidate(config) else "staged",
+    parts = ["fused" if fused_engages(config) else "staged",
              f"score={score}", f"partition={part}"]
     spec = config.hierarchy
     if not spec.is_flat:
@@ -243,15 +228,13 @@ def degradation_ladder(config: PipelineConfig) -> list:
             cur = new
             rungs.append((name, new))
 
-    if fused_candidate(config):
+    if fused_engages(config):
         push("unfused", fused="off")
     for backend in _BACKEND_CHAIN[config.score_backend][1:]:
         push(f"score_{backend}", score_backend=backend)
     if config.partition_backend != "numpy":
         push("partition_numpy", partition_backend="numpy")
-    # hierarchy-depth degradation: hierarchy is a normalized
-    # HierarchySpec here, so the rungs are built with its own
-    # combinators (never the deprecated legacy kwargs)
+    # hierarchy-depth degradation, built with the spec's own combinators
     spec = cur.hierarchy
     if spec.depth > 2:
         push("depth_2", hierarchy=spec.truncated(2))

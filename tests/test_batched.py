@@ -11,8 +11,9 @@ backends.
   ``uneven_prime``;
 - the jax scoring backend must match numpy within fp tolerance on every
   metric key and fall back to numpy cleanly when jax is unavailable;
-- ``MappingPipeline`` with ``sweep="batched"`` must return mappings and
-  winners bit-identical to the ``sweep="loop"`` oracle.
+- ``MappingPipeline``'s batched sweep must return mappings and winners
+  bit-identical to the per-candidate oracle (``map_candidate`` for every
+  rotation, then the search's winner).
 
 Property-style via seeded numpy RNG (no hypothesis dependency)."""
 
@@ -250,6 +251,19 @@ def test_order_points_batched_hilbert_parity(seed):
 # pipeline: batched sweep == loop oracle, end to end
 # ---------------------------------------------------------------------------
 
+def _loop_oracle(pipe, g, alloc):
+    """Every rotation through ``map_candidate``, then the search's
+    winner: the per-candidate oracle of the batched sweep."""
+    pc = pipe.machine_coords(alloc)
+    tc = g.coords.astype(float)
+    cands = rotation_candidates(tc.shape[1], pc.shape[1],
+                                pipe.config.rotations)
+    results = [pipe.map_candidate(tc, pc, task_perm=c.task_perm,
+                                  proc_perm=c.proc_perm) for c in cands]
+    best, _, _ = pipe.search.best(g, alloc, results)
+    return results, best
+
+
 @pytest.mark.parametrize("cfg_kw", [
     dict(sfc="FZ", rotations=12),
     dict(sfc="FZ", rotations=12, uneven_prime=True, bandwidth_scale=True),
@@ -260,17 +274,16 @@ def test_pipeline_sweep_matches_loop(cfg_kw):
     m = make_machine((8, 8, 8), wrap=True)
     alloc = sfc_allocation(m, 64, nfragments=2, seed=3)
     g = stencil_graph((8, 8))
-    loop = MappingPipeline(PipelineConfig(sweep="loop", **cfg_kw))
-    bat = MappingPipeline(PipelineConfig(sweep="batched", **cfg_kw))
-    pc = loop.machine_coords(alloc)
+    pipe = MappingPipeline(PipelineConfig(**cfg_kw))
+    pc = pipe.machine_coords(alloc)
     tc = g.coords.astype(float)
     cands = rotation_candidates(2, 3, cfg_kw["rotations"])
-    rl = loop.map_candidates(tc, pc, cands)
-    rb = bat.map_candidates(tc, pc, cands)
+    rl, res_l = _loop_oracle(pipe, g, alloc)
+    rb = pipe.map_candidates(tc, pc, cands)
+    assert len(rl) == len(rb)
     for x, y in zip(rl, rb):
         assert np.array_equal(x.task_to_proc, y.task_to_proc)
-    res_l = loop.map(g, alloc)
-    res_b = bat.map(g, alloc)
+    res_b = pipe.map(g, alloc)
     assert np.array_equal(res_l.task_to_proc, res_b.task_to_proc)
     assert res_l.rotation == res_b.rotation
 
@@ -281,10 +294,9 @@ def test_pipeline_sweep_matches_loop_tnum_gt_pnum():
     m = make_machine((8, 8, 8), wrap=True)
     alloc = sfc_allocation(m, 64, seed=1)
     g = stencil_graph((16, 16))
-    kw = dict(sfc="FZ", rotations=8)
-    res_l = MappingPipeline(PipelineConfig(sweep="loop", **kw)).map(g, alloc)
-    res_b = MappingPipeline(
-        PipelineConfig(sweep="batched", **kw)).map(g, alloc)
+    pipe = MappingPipeline(PipelineConfig(sfc="FZ", rotations=8))
+    _, res_l = _loop_oracle(pipe, g, alloc)
+    res_b = pipe.map(g, alloc)
     assert np.array_equal(res_l.task_to_proc, res_b.task_to_proc)
 
 

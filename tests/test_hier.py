@@ -4,16 +4,15 @@ Covers: geometric aggregation (balance, centroids, volume
 conservation), the router view, the two-level map (bijection, exact
 coarse == fine volume-weighted metrics, the ~cores_per_node x
 engine-pass point reduction, quality vs flat), the monotone swap
-refinement, and the Mapper / meshmap wiring."""
+refinement, and the pipeline / meshmap wiring."""
 
 import numpy as np
 import pytest
 
 from repro import obs
-from repro.core import (Mapper, MapperConfig, TaskGraph, evaluate,
-                        gemini_xk7, identity_mapping, logical_mesh_graph,
-                        make_machine, sfc_allocation, stencil_graph,
-                        tpu_v5e_multipod)
+from repro.core import (TaskGraph, evaluate, gemini_xk7, identity_mapping,
+                        logical_mesh_graph, make_machine, sfc_allocation,
+                        stencil_graph, tpu_v5e_multipod)
 from repro.core.machine import Allocation
 from repro.core.metrics import evaluate_candidates
 from repro.hier import (aggregate_tasks, assign_cores, refine_swaps,
@@ -26,6 +25,10 @@ def _grid(n):
     e = int(np.log2(n))
     a = e // 3
     return (1 << (e - 2 * a), 1 << a, 1 << a)
+
+
+def _pipe(**kw):
+    return MappingPipeline(PipelineConfig(**kw))
 
 
 def _xk7_case(side=8, cores=16, nfragments=4, seed=1):
@@ -115,9 +118,9 @@ def test_router_view_roundtrip():
 
 def test_hier_bijection_and_quality_vs_flat():
     m, alloc, g = _xk7_case()
-    flat = Mapper(MapperConfig(sfc="FZ", shift=True, rotations=8))
-    node = Mapper(MapperConfig(sfc="FZ", shift=True, rotations=8,
-                               hierarchy=HierarchySpec.node()))
+    flat = _pipe(sfc="FZ", shift=True, rotations=8)
+    node = _pipe(sfc="FZ", shift=True, rotations=8,
+                 hierarchy=HierarchySpec.node())
     rf, rn = flat.map(g, alloc), node.map(g, alloc)
     assert np.array_equal(np.sort(rn.task_to_proc), np.arange(g.n))
     ef, en = evaluate(g, alloc, rf), evaluate(g, alloc, rn)
@@ -128,8 +131,8 @@ def test_hier_bijection_and_quality_vs_flat():
 
 def test_hier_point_reduction_matches_cores_per_node():
     m, alloc, g = _xk7_case()
-    flat = Mapper(MapperConfig(sfc="FZ")).map(g, alloc)
-    node = Mapper(MapperConfig(sfc="FZ", hierarchy=HierarchySpec.node())).map(g, alloc)
+    flat = _pipe(sfc="FZ").map(g, alloc)
+    node = _pipe(sfc="FZ", hierarchy=HierarchySpec.node()).map(g, alloc)
     assert flat.stats["sweep_points"] == 2 * g.n
     assert node.stats["sweep_points"] == 2 * g.n // 16
     assert flat.stats["sweep_points"] / node.stats["sweep_points"] == 16
@@ -141,16 +144,16 @@ def test_coarse_score_equals_fine_weighted_hops():
     """Every task carries its node's router coordinates, so the
     contracted graph's weighted_hops is EXACTLY the fine mapping's."""
     m, alloc, g = _xk7_case(nfragments=2, seed=5)
-    rn = Mapper(MapperConfig(sfc="FZ", hierarchy=HierarchySpec.node())).map(g, alloc)
+    rn = _pipe(sfc="FZ", hierarchy=HierarchySpec.node()).map(g, alloc)
     fine = evaluate(g, alloc, rn)["weighted_hops"]
     assert rn.score == rn.stats["refine_final"] == fine
 
 
 def test_hierarchy_flat_is_default_and_unchanged():
     m, alloc, g = _xk7_case(nfragments=2, seed=3)
-    default = Mapper(MapperConfig(sfc="FZ", rotations=4)).map(g, alloc)
-    flat = Mapper(MapperConfig(sfc="FZ", rotations=4,
-                               hierarchy="flat")).map(g, alloc)
+    default = _pipe(sfc="FZ", rotations=4).map(g, alloc)
+    flat = _pipe(sfc="FZ", rotations=4,
+                 hierarchy=HierarchySpec.flat()).map(g, alloc)
     assert np.array_equal(default.task_to_proc, flat.task_to_proc)
     assert default.stats["hierarchy"] == "flat"
 
@@ -377,11 +380,12 @@ def test_fused_refinement_ladder_unfused_rung_bit_identical():
     result — degradation moves WHERE the algorithm runs, not what it
     returns."""
     pytest.importorskip("jax")
-    from repro.serve.resilience import degradation_ladder, fused_candidate
+    from repro.mapping.pipeline import fused_engages
+    from repro.serve.resilience import degradation_ladder
     m, alloc, g = _fused_refine_case()
     cfg = PipelineConfig(sfc="H", rotations=6, hierarchy=HierarchySpec.node(),
                          partition_backend="jax", score_backend="jax")
-    assert fused_candidate(cfg)
+    assert fused_engages(cfg)
     ladder = dict(degradation_ladder(cfg))
     assert "unfused" in ladder
     full = MappingPipeline(cfg).map(g, alloc)

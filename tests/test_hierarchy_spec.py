@@ -2,17 +2,15 @@
 
 Covers the :class:`repro.hier.HierarchySpec` API end to end:
 
-- depth-2 spec reproduces the legacy ``hierarchy="node"`` path BIT
-  FOR BIT (winners and refine trajectory);
+- constructors, validation and the ladder's combinators;
 - depth-1 spec is the flat pipeline;
 - depth-3/4 maps keep the bijection and monotone-objective invariants
   across the scenario registry;
 - equal specs built through different constructors canonicalise to
   the SAME config signature (cache-key stability);
-- unknown hierarchies fail at CONFIG CONSTRUCTION with a ValueError
-  listing the accepted values;
-- the deprecation shim: legacy strings / flat refine kwargs map onto
-  the equivalent spec with a single DeprecationWarning.
+- anything but a spec fails at CONFIG CONSTRUCTION with a ValueError
+  naming ``HierarchySpec``, and the retired flat refine fields are not
+  config fields at all.
 """
 
 import dataclasses
@@ -21,14 +19,13 @@ import warnings
 import numpy as np
 import pytest
 
-from repro.core import (Mapper, MapperConfig, evaluate, gemini_xk7,
-                        sfc_allocation, stencil_graph)
+from repro.core import evaluate, gemini_xk7, sfc_allocation, stencil_graph
 from repro.core.signature import config_signature
 from repro.hier import (HierarchySpec, Level, group_units, polish_groups,
                         refine_qap)
 from repro.hier.spec import (DEFAULT_GROUP_ARITY, DEFAULT_QAP_ROUNDS,
                              DEFAULT_REFINE_ROUNDS)
-from repro.mapping import PipelineConfig
+from repro.mapping import MappingPipeline, PipelineConfig
 from repro.serve.scenarios import Scenario
 
 
@@ -49,7 +46,7 @@ def _xk7_case(side=8, cores=16, nfragments=4, seed=1):
 def _map(g, alloc, **kw):
     kw.setdefault("sfc", "H")
     kw.setdefault("rotations", 4)
-    return Mapper(MapperConfig(**kw)).map(g, alloc)
+    return MappingPipeline(PipelineConfig(**kw)).map(g, alloc)
 
 
 # ---------------------------------------------------------------------------
@@ -80,7 +77,7 @@ def test_with_depth_default_budgets():
     assert s.levels[1].refine_mode == "qap"
     assert s.levels[1].refine_rounds == DEFAULT_QAP_ROUNDS
     assert s.levels[1].arity == DEFAULT_GROUP_ARITY
-    # an explicit refine_rounds applies to every level (legacy fold)
+    # an explicit refine_rounds applies to every level
     s5 = HierarchySpec.with_depth(3, refine_rounds=5)
     assert all(lv.refine_rounds == 5 for lv in s5.levels)
     # depth 2 keeps the bit-identity budget
@@ -110,15 +107,18 @@ def test_level_validation():
 
 def test_unknown_hierarchy_raises_at_config_construction():
     # the 4xx-style error arrives when the CONFIG is built — before any
-    # mapping work, serve admission or degradation rung — and lists the
-    # accepted values
-    for bad in ("mesh", "depthX", "three-level"):
-        with pytest.raises(ValueError, match="flat.*node.*depth<N>"):
-            MapperConfig(hierarchy=bad)
-    with pytest.raises(ValueError, match="HierarchySpec"):
-        MapperConfig(hierarchy=42)
-    with pytest.raises(ValueError, match="depth<N>"):
-        PipelineConfig(hierarchy="nod")
+    # mapping work, serve admission or degradation rung — and names the
+    # spec type and the parser of hierarchy names
+    for bad in ("node", "mesh", 42):
+        with pytest.raises(ValueError,
+                           match="HierarchySpec.*HierarchySpec.from_string"):
+            PipelineConfig(hierarchy=bad)
+    # the retired flat refine knobs are not config fields
+    with pytest.raises(TypeError):
+        PipelineConfig(refine_rounds=2)
+    # the names parser still lists what it accepts
+    with pytest.raises(ValueError, match="flat.*node.*depth<N>"):
+        HierarchySpec.from_string("nod")
 
 
 def test_spec_combinators():
@@ -130,72 +130,18 @@ def test_spec_combinators():
     assert [lv.name for lv in z.levels] == [lv.name for lv in s.levels]
 
 
-# ---------------------------------------------------------------------------
-# deprecation shim
-# ---------------------------------------------------------------------------
-
-def test_legacy_node_string_warns_once_and_normalises():
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        cfg = MapperConfig(hierarchy="node")
-    dep = [w for w in rec if issubclass(w.category, DeprecationWarning)]
-    assert len(dep) == 1
-    assert "HierarchySpec" in str(dep[0].message)
-    assert cfg.hierarchy == HierarchySpec.node()
-    assert cfg.refine_rounds is None  # folded into the spec
-
-
-def test_legacy_refine_kwargs_fold_into_spec():
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        cfg = MapperConfig(hierarchy="node", refine_rounds=3,
-                           refine_top=16)
-    assert len([w for w in rec
-                if issubclass(w.category, DeprecationWarning)]) == 1
-    lv = cfg.hierarchy.levels[0]
-    assert lv.refine_rounds == 3 and lv.refine_top == 16
-    # kwargs also fold onto an explicit spec
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        cfg = PipelineConfig(hierarchy=HierarchySpec.with_depth(3),
-                             refine_rounds=0)
-    assert len([w for w in rec
-                if issubclass(w.category, DeprecationWarning)]) == 1
-    assert cfg.hierarchy.refine_rounds_total == 0
-
-
 def test_flat_default_is_warning_free():
     with warnings.catch_warnings():
         warnings.simplefilter("error", DeprecationWarning)
-        cfg = MapperConfig()
+        cfg = PipelineConfig()
         assert cfg.hierarchy == HierarchySpec.flat()
         cfg = PipelineConfig(hierarchy=HierarchySpec.with_depth(3))
         assert cfg.hierarchy.depth == 3
 
 
-def test_renormalising_is_idempotent():
-    with warnings.catch_warnings():
-        warnings.simplefilter("error", DeprecationWarning)
-        cfg = PipelineConfig(hierarchy=HierarchySpec.node())
-        again = dataclasses.replace(cfg, rotations=2)  # re-runs shim
-    assert again.hierarchy == cfg.hierarchy
-
-
 # ---------------------------------------------------------------------------
-# equivalence: depth-2 == legacy node, depth-1 == flat
+# equivalence: depth-1 == flat
 # ---------------------------------------------------------------------------
-
-def test_depth2_bit_identical_to_legacy_node():
-    _, alloc, g = _xk7_case()
-    new = _map(g, alloc, hierarchy=HierarchySpec.node())
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        old = _map(g, alloc, hierarchy="node")
-    assert np.array_equal(new.task_to_proc, old.task_to_proc)
-    assert new.rotation == old.rotation
-    assert new.score == old.score
-    assert new.stats["refine_history"] == old.stats["refine_history"]
-
 
 def test_depth1_is_flat():
     _, alloc, g = _xk7_case(side=4)
@@ -306,13 +252,6 @@ def test_signature_stable_across_construction_paths():
     ]
     sigs = {config_signature(PipelineConfig(hierarchy=s)) for s in paths}
     assert len(sigs) == 1
-    # ... and the legacy string lands on the SAME cache key as the spec
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        legacy = config_signature(MapperConfig(hierarchy="node"))
-    assert legacy == config_signature(
-        MapperConfig(hierarchy=HierarchySpec.node()))
-    assert legacy != config_signature(MapperConfig())  # flat differs
 
 
 def test_signature_distinguishes_depths_and_budgets():

@@ -7,12 +7,12 @@ pytest.importorskip("hypothesis")  # optional dev dep (requirements-dev.txt)
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.core import (Mapper, MapperConfig, block_allocation,
-                        closest_subset, cube_sphere_graph, evaluate,
-                        geometric_map, identity_mapping, make_machine,
-                        sfc_allocation, shift_torus, stencil_graph,
-                        tpu_v5e_multipod)
+from repro.core import (block_allocation, closest_subset,
+                        cube_sphere_graph, evaluate, identity_mapping,
+                        make_machine, sfc_allocation, shift_torus,
+                        stencil_graph, tpu_v5e_multipod)
 from repro.core.transforms import box_lift, scale_by_bandwidth
+from repro.mapping import MappingPipeline, PipelineConfig
 
 
 def _grid_coords(shape):
@@ -20,13 +20,23 @@ def _grid_coords(shape):
     return np.stack([c.ravel() for c in ix], axis=1).astype(float)
 
 
+def _algorithm1(task_coords, proc_coords, **kw):
+    """Algorithm 1 for the identity rotation."""
+    return MappingPipeline(PipelineConfig(**kw)).map_candidate(
+        task_coords, proc_coords)
+
+
+def _map(g, alloc, **kw):
+    return MappingPipeline(PipelineConfig(**kw)).map(g, alloc)
+
+
 # ---------------------------------------------------------------------------
-# geometric_map (Algorithm 1)
+# Algorithm 1 (one rotation)
 # ---------------------------------------------------------------------------
 
 def test_one_to_one_same_coords_is_identity():
     coords = _grid_coords((4, 4))
-    res = geometric_map(coords, coords, sfc="FZ")
+    res = _algorithm1(coords, coords, sfc="FZ")
     assert np.array_equal(res.task_to_proc, np.arange(16))
 
 
@@ -35,7 +45,7 @@ def test_one_to_one_is_bijection(sfc):
     rng = np.random.default_rng(0)
     tc = rng.normal(size=(64, 2))
     pc = rng.normal(size=(64, 3))
-    res = geometric_map(tc, pc, sfc=sfc)
+    res = _algorithm1(tc, pc, sfc=sfc)
     assert sorted(res.task_to_proc.tolist()) == list(range(64))
 
 
@@ -43,7 +53,7 @@ def test_more_tasks_than_procs_balanced():
     rng = np.random.default_rng(1)
     tc = rng.normal(size=(64, 2))
     pc = rng.normal(size=(16, 2))
-    res = geometric_map(tc, pc, sfc="FZ")
+    res = _algorithm1(tc, pc, sfc="FZ")
     counts = np.bincount(res.task_to_proc, minlength=16)
     assert (counts == 4).all()
 
@@ -53,7 +63,7 @@ def test_fewer_tasks_than_procs_uses_subset():
     tc = rng.normal(size=(8, 2))
     pc = np.concatenate([rng.normal(size=(8, 2)),
                          rng.normal(size=(24, 2)) + 100.0])
-    res = geometric_map(tc, pc, sfc="FZ")
+    res = _algorithm1(tc, pc, sfc="FZ")
     # chosen procs must form ONE compact cluster (either group works; the
     # selection must not straddle the 100-unit gap)
     chosen = res.task_to_proc
@@ -75,13 +85,13 @@ def test_closest_subset_picks_cluster():
 def test_mfz_auto_engages_only_when_pd_multiple_of_td():
     tc = _grid_coords((64,))  # td=1
     pc = _grid_coords((8, 8))  # pd=2
-    res_mfz = geometric_map(tc, pc, sfc="FZ", mfz="auto")
-    res_fz = geometric_map(tc, pc, sfc="FZ", mfz=False)
+    res_mfz = _algorithm1(tc, pc, sfc="FZ", mfz="auto")
+    res_fz = _algorithm1(tc, pc, sfc="FZ", mfz=False)
     assert not np.array_equal(res_mfz.task_to_proc, res_fz.task_to_proc)
     # pd == td: MFZ must be identical to FZ
     pc2 = _grid_coords((64,))
-    a = geometric_map(tc, pc2, sfc="FZ", mfz="auto")
-    b = geometric_map(tc, pc2, sfc="FZ", mfz=False)
+    a = _algorithm1(tc, pc2, sfc="FZ", mfz="auto")
+    b = _algorithm1(tc, pc2, sfc="FZ", mfz=False)
     assert np.array_equal(a.task_to_proc, b.task_to_proc)
 
 
@@ -122,7 +132,7 @@ def test_box_lift_shape_and_weighting():
 
 
 # ---------------------------------------------------------------------------
-# Mapper end-to-end on machines
+# The full pipeline end-to-end on machines
 # ---------------------------------------------------------------------------
 
 def test_mapper_beats_identity_on_sparse_allocation():
@@ -131,8 +141,7 @@ def test_mapper_beats_identity_on_sparse_allocation():
     m = make_machine((16, 16), wrap=True)
     alloc = sfc_allocation(m, 64, nfragments=4, seed=7)
     g = stencil_graph((8, 8))
-    mapper = Mapper(MapperConfig(sfc="FZ", shift=True))
-    res = mapper.map(g, alloc)
+    res = _map(g, alloc, sfc="FZ", shift=True)
     ours = evaluate(g, alloc, res)
     base = evaluate(g, alloc, identity_mapping(g, alloc))
     assert ours["total_hops"] <= base["total_hops"]
@@ -142,8 +151,8 @@ def test_mapper_rotation_search_not_worse():
     m = make_machine((8, 8, 8), wrap=True)
     alloc = sfc_allocation(m, 64, nfragments=2, seed=3)
     g = stencil_graph((8, 8))
-    plain = Mapper(MapperConfig(sfc="FZ", rotations=0)).map(g, alloc)
-    rot = Mapper(MapperConfig(sfc="FZ", rotations=12)).map(g, alloc)
+    plain = _map(g, alloc, sfc="FZ", rotations=0)
+    rot = _map(g, alloc, sfc="FZ", rotations=12)
     h_plain = evaluate(g, alloc, plain)["weighted_hops"]
     h_rot = evaluate(g, alloc, rot)["weighted_hops"]
     assert h_rot <= h_plain + 1e-9
@@ -153,7 +162,7 @@ def test_mapper_on_tpu_multipod():
     m = tpu_v5e_multipod(npods=2, side=4)
     alloc = block_allocation(m)
     g = stencil_graph((4, 8))
-    res = Mapper(MapperConfig(sfc="FZ")).map(g, alloc)
+    res = _map(g, alloc, sfc="FZ")
     assert sorted(res.task_to_proc.tolist()) == list(range(32))
 
 
@@ -185,5 +194,5 @@ def test_mapping_valid_any_machine(side, d, seed):
     alloc = block_allocation(m)
     n = side ** d
     g = stencil_graph((n,))  # 1D chain of matching size
-    res = Mapper(MapperConfig(sfc="FZ")).map(g, alloc)
+    res = _map(g, alloc, sfc="FZ")
     assert sorted(res.task_to_proc.tolist()) == list(range(n))

@@ -23,8 +23,9 @@ import numpy as np
 import pytest
 
 from repro import faults, obs
-from repro.core import Mapper, MapperConfig, make_machine, stencil_graph
+from repro.core import make_machine, stencil_graph
 from repro.hier import HierarchySpec
+from repro.mapping import MappingPipeline, PipelineConfig
 from repro.core.machine import block_allocation
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import Tracer
@@ -353,7 +354,8 @@ def _flat_case():
 
 def test_flat_timings_schema_is_span_derived():
     m, alloc, g = _flat_case()
-    res = Mapper(MapperConfig(sfc="FZ", rotations=4)).map(g, alloc)
+    res = MappingPipeline(PipelineConfig(sfc="FZ", rotations=4)).map(
+        g, alloc)
     t = res.stats["timings"]
     assert {"partition_s", "score_s", "total_s"} <= set(t)
     assert "fused_s" not in t
@@ -367,7 +369,7 @@ def test_flat_timings_schema_is_span_derived():
 
 def test_hier_timings_schema_is_span_derived():
     m, alloc, g = _flat_case()
-    res = Mapper(MapperConfig(
+    res = MappingPipeline(PipelineConfig(
         sfc="FZ", rotations=4,
         hierarchy=HierarchySpec.node())).map(g, alloc)
     t = res.stats["timings"]

@@ -12,10 +12,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from repro.core import (Allocation, Mapper, MapperConfig, evaluate,
-                        identity_mapping, logical_mesh_graph,
-                        stencil_graph, sfc_allocation, tpu_v5e_pod,
-                        make_machine)
+from repro.core import (Allocation, evaluate, identity_mapping,
+                        logical_mesh_graph, stencil_graph, sfc_allocation,
+                        tpu_v5e_pod, make_machine)
+from repro.mapping import MappingPipeline, PipelineConfig
 from repro.models import ModelConfig, params_spec, tree_init
 from repro.models.config import ShapeConfig
 from repro.serve.engine import ServeEngine
@@ -57,7 +57,8 @@ def test_geometric_mapping_improves_sparse_stencil():
     m = make_machine((16, 16, 16), wrap=True, bw=1.0)
     alloc = sfc_allocation(m, 512, nfragments=8, seed=11)
     g = stencil_graph((8, 8, 8))
-    geo = Mapper(MapperConfig(sfc="FZ", shift=True)).map(g, alloc)
+    geo = MappingPipeline(PipelineConfig(sfc="FZ", shift=True)).map(
+        g, alloc)
     ours = evaluate(g, alloc, geo)
     base = evaluate(g, alloc, identity_mapping(g, alloc))
     assert ours["average_hops"] < base["average_hops"]
